@@ -1,10 +1,11 @@
-"""Benchmark: COM ring vs GSPMD all-reduce — ICI bytes + HLO structure.
+"""COM ring vs GSPMD all-reduce: collective bytes counted in compiled HLO.
 
-The TPU-side validation of the paper's data-movement claim: for the same
-row-parallel matmul, Domino's COM reduce-scatter moves half the bytes of the
-baseline all-reduce and lowers to neighbour collective-permutes only (no
-global reduction op). Runs on 8 forced host devices in a subprocess to keep
-the caller's device state clean.
+For the same row-parallel matmul, Domino's COM reduce-scatter should move
+half the bytes of the baseline all-reduce and lower to neighbour
+collective-permutes only (no global reduction op). This script compiles
+both for 8 forced host devices on the CPU, in a child process pinned to
+the CPU, and counts the collective bytes in the HLO. It is a byte count
+from the compiler, not a measurement on a TPU.
 """
 from __future__ import annotations
 
@@ -18,15 +19,15 @@ _CHILD = textwrap.dedent(
     """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import sys, json
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from repro.parallel.collectives import matmul_strategy, wire_bytes
     from repro.launch.hlo_analysis import analyze_hlo
+    from jax.sharding import AxisType
 
-    from repro.core import jax_compat
-
-    mesh = jax_compat.make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     M, K, N = 256, 4096, 2048
     x = jnp.ones((M, K), jnp.bfloat16)
     w = jnp.ones((K, N), jnp.bfloat16)

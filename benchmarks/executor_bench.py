@@ -83,15 +83,12 @@ def main(argv=None) -> int:
     events_match = all(oracle.events[f] == totals[f] for f in EVENT_FIELDS)
 
     interpret = None
-    compile_cache = None
     if "jax" in args.backends:
         from repro.core.executor import default_interpret
-        from repro.core.jax_compat import maybe_init_compile_cache
+        from repro.launch.compile_cache import init_compile_cache
 
         interpret = default_interpret()
-        # REPRO_COMPILE_CACHE=<dir>: persistent XLA cache — repeat runs
-        # skip jit compilation of the whole chain (recorded in the payload)
-        compile_cache = maybe_init_compile_cache()
+        init_compile_cache()
 
     shard = args.shard == "auto" and "jax" in args.backends
     batches = {}
@@ -175,7 +172,6 @@ def main(argv=None) -> int:
         import jax
 
         payload["n_devices"] = len(jax.devices())
-        payload["compile_cache"] = compile_cache
     if shard:
         payload["n_shards"] = n_shards
         payload["sharded_matches_jax"] = sharded_matches

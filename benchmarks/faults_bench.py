@@ -127,8 +127,10 @@ def bench_executor(network: str, batch: int, seed: int,
     interpret = None
     if run_jax:
         from repro.core.executor import default_interpret
+        from repro.launch.compile_cache import init_compile_cache
 
         interpret = default_interpret()
+        init_compile_cache()
 
     out = dict(network=network, batch=batch,
                logits_checksum_r0=checksum,

@@ -58,6 +58,9 @@ def rows():
 
 
 def main():
+    from repro.launch.compile_cache import init_compile_cache
+
+    init_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.1f},{derived}")
 

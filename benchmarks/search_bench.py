@@ -120,11 +120,10 @@ def main(argv=None) -> int:
                     help="write JSON here (default: stdout)")
     args = ap.parse_args(argv)
 
-    compile_cache = None
     if args.backend == "jax":
-        from repro.core.jax_compat import maybe_init_compile_cache
+        from repro.launch.compile_cache import init_compile_cache
 
-        compile_cache = maybe_init_compile_cache()
+        init_compile_cache()
 
     t_start = time.perf_counter()
     networks = {}
@@ -174,7 +173,6 @@ def main(argv=None) -> int:
         strictly_better_any=any_strict,
         greedy_matches_baseline=all_base,
         energy_ratio_mean=sum(ratios) / len(ratios) if ratios else 1.0,
-        compile_cache=compile_cache,
     )
 
     if args.pareto_budget > 0:

@@ -153,9 +153,11 @@ def main(argv=None) -> int:
     import jax
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import init_compile_cache
     from repro.models.transformer import CallConfig, build_model
     from repro.serve.engine import Engine
 
+    init_compile_cache()
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg, CallConfig(remat="none"))
     params = model.init(jax.random.PRNGKey(0))

@@ -191,11 +191,9 @@ def main(argv=None) -> int:
     if args.sharded:
         backends = backends + ("jax-sharded",)
     if any(b.startswith("jax") for b in backends):
-        # REPRO_COMPILE_CACHE=<dir>: persistent XLA cache for the jitted
-        # sweep kernels (opt-in no-op otherwise)
-        from repro.core.jax_compat import maybe_init_compile_cache
+        from repro.launch.compile_cache import init_compile_cache
 
-        maybe_init_compile_cache()
+        init_compile_cache()
     results = {}
     timings = {}  # backend -> best engine_wall_s (repeats warm caches/jit)
     for backend in backends:

@@ -2,7 +2,8 @@
 
     PYTHONPATH=src python examples/com_collectives.py
 
-Runs in a subprocess with 8 forced host devices: compares the GSPMD
+Runs in a subprocess pinned to the CPU with 8 forced host devices
+(forced host devices exist only there): compares the GSPMD
 all-reduce baseline against the COM ring (reduce-scatter built from
 ppermute with per-hop accumulation + fused ROFM epilogue), verifying both
 numerics and the 2x ICI-byte reduction from the compiled HLO.
@@ -15,16 +16,16 @@ import textwrap
 CHILD = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import sys
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from repro.core.com import make_com_matmul
     from repro.parallel.collectives import matmul_strategy
     from repro.launch.hlo_analysis import analyze_hlo
+    from jax.sharding import AxisType
 
-    from repro.core import jax_compat
-
-    mesh = jax_compat.make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (64, 512), jnp.float32)
     w = jax.random.normal(jax.random.fold_in(key, 1), (512, 256), jnp.float32)

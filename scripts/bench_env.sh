@@ -28,6 +28,3 @@ export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 # respect a caller/CI-provided XLA_FLAGS (the multi-device jobs force
 # --xla_force_host_platform_device_count=8); nothing forced by default.
 export XLA_FLAGS="${XLA_FLAGS:-}"
-
-# persistent jit-compile cache (repro.core.jax_compat honors this)
-export REPRO_COMPILE_CACHE="${REPRO_COMPILE_CACHE:-${HOME}/.cache/repro-jax}"

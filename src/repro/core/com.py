@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import jax_compat
-
 
 def _ring_perm(n: int, shift: int = 1):
     return [(i, (i + shift) % n) for i in range(n)]
@@ -189,9 +187,9 @@ def make_com_matmul(mesh: Mesh, axis: str = "model"):
         if residual is not None:
             extra.append(residual)
             extra_specs.append(out_spec)
-        return jax_compat.shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=tuple(specs + extra_specs),
-            out_specs=out_spec,
+            out_specs=out_spec, check_vma=False,
         )(x, w, *extra)
 
     return com_mm

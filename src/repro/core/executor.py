@@ -149,6 +149,76 @@ def _maxpool_np(x: np.ndarray, k: int, s: int) -> np.ndarray:
     return out
 
 
+def jax_forward(program, *, interpret: bool,
+                block_m: Optional[int] = None, block_n: Optional[int] = None,
+                block_k: Optional[int] = None):
+    """The jax backend's whole layer chain as one pure function.
+
+    ``forward(x, ws)`` maps float32 images ``(B, *input_shape)`` and the
+    float32 weights ``ws`` (one per layer, in layer order) to logits, with
+    one Pallas ``com_matmul`` call per conv/FC layer. The executor jits
+    it (:meth:`ProgramExecutor.lower`); compiling it yourself from
+    abstract inputs (``jax.jit(forward).lower(x, ws).compile()``) gives
+    the executable's HLO text and memory analysis for a described TPU.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.com_matmul import com_matmul_padded
+
+    # MXU-aligned 128 blocks on real TPUs; interpret mode unrolls the
+    # grid into the jitted graph, so bigger blocks (fewer, larger
+    # dots) are what make the CPU CI path fast — 512³ blocks run a
+    # B=32 VGG-11 chain faster than the batched NumPy oracle.
+    default_block = 512 if interpret else 128
+    bm, bn, bk = (b if b is not None else default_block
+                  for b in (block_m, block_n, block_k))
+    layer_programs = program.layer_programs
+
+    def matmul(x2d, w2d):
+        # one COM kernel call per layer matmul: the K-grid walks the
+        # C-block chain, partial sums riding the f32 VMEM scratch;
+        # the ReLU epilogue fuses into the last K step (M-type ACT)
+        return com_matmul_padded(
+            x2d, w2d, activation="relu",
+            block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+        )
+
+    def forward(x, ws):
+        for lp, w in zip(layer_programs, ws):
+            l = lp.layer
+            if isinstance(l, ConvSpec):
+                K, P, S = l.k, l.padding, l.stride
+                Ho, Wo = l.h_out, l.w_out
+                xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
+                cols = [
+                    xp[:, kr:kr + (Ho - 1) * S + 1:S,
+                       kc:kc + (Wo - 1) * S + 1:S, :]
+                    for kr in range(K) for kc in range(K)
+                ]
+                # im2col in (kr, kc, c) order == w.reshape row-major
+                patches = jnp.concatenate(cols, axis=-1)
+                B = x.shape[0]
+                y = matmul(
+                    patches.reshape(B * Ho * Wo, K * K * l.c_in),
+                    w.reshape(K * K * l.c_in, l.c_out),
+                ).reshape(B, Ho, Wo, l.c_out)
+                if l.pool_k > 0:
+                    y = jax.lax.reduce_window(
+                        y, -jnp.inf, jax.lax.max,
+                        (1, l.pool_k, l.pool_k, 1),
+                        (1, l.pool_stride, l.pool_stride, 1), "VALID",
+                    )
+                x = y
+            else:
+                if x.ndim > 2:
+                    x = x.reshape(x.shape[0], -1)
+                x = matmul(x, w)
+        return x
+
+    return forward
+
+
 @dataclass(frozen=True)
 class ExecutionResult:
     """One batched program run: outputs + per-image events + timing."""
@@ -219,7 +289,7 @@ class ProgramExecutor:
             self.weights, self.fault_info = apply_weight_faults(
                 layers, self.weights, self.faults, program.arch)
         self._events: Optional[Dict[str, int]] = None
-        self._jax_forward = None
+        self._jax_chain = None
         self._mesh = self._resolve_shard(shard, backend)
 
     @staticmethod
@@ -322,73 +392,21 @@ class ProgramExecutor:
 
     # ---- jax backend: block chains lowered to the Pallas COM kernel ----
     def _build_jax(self):
+        """``(jit_forward, ws, place)``: the jitted chain, its float32
+        weights on the device, and the map from a float32 batch to the
+        chain's input (padded and sharded in sharded mode)."""
         import jax
         import jax.numpy as jnp
-
-        from repro.core.jax_compat import maybe_init_compile_cache
-        from repro.kernels.com_matmul import com_matmul_padded
-
-        # opt-in persistent XLA cache (REPRO_COMPILE_CACHE=<dir>): repeat
-        # runs load the jitted chain instead of recompiling it
-        maybe_init_compile_cache()
 
         interpret = self.interpret
         if interpret is None:
             interpret = default_interpret()
-        # MXU-aligned 128 blocks on real TPUs; interpret mode unrolls the
-        # grid into the jitted graph, so bigger blocks (fewer, larger
-        # dots) are what make the CPU CI path fast — 512³ blocks run a
-        # B=32 VGG-11 chain faster than the batched NumPy oracle.
-        default_block = 512 if interpret else 128
-        bm, bn, bk = (b if b is not None else default_block
-                      for b in self.blocks)
-        layer_programs = self.program.layer_programs
-
-        def matmul(x2d, w2d):
-            # one COM kernel call per layer matmul: the K-grid walks the
-            # C-block chain, partial sums riding the f32 VMEM scratch;
-            # the ReLU epilogue fuses into the last K step (M-type ACT)
-            return com_matmul_padded(
-                x2d, w2d, activation="relu",
-                block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
-            )
-
-        def forward(x, ws):
-            for lp, w in zip(layer_programs, ws):
-                l = lp.layer
-                if isinstance(l, ConvSpec):
-                    K, P, S = l.k, l.padding, l.stride
-                    Ho, Wo = l.h_out, l.w_out
-                    xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
-                    cols = [
-                        xp[:, kr:kr + (Ho - 1) * S + 1:S,
-                           kc:kc + (Wo - 1) * S + 1:S, :]
-                        for kr in range(K) for kc in range(K)
-                    ]
-                    # im2col in (kr, kc, c) order == w.reshape row-major
-                    patches = jnp.concatenate(cols, axis=-1)
-                    B = x.shape[0]
-                    y = matmul(
-                        patches.reshape(B * Ho * Wo, K * K * l.c_in),
-                        w.reshape(K * K * l.c_in, l.c_out),
-                    ).reshape(B, Ho, Wo, l.c_out)
-                    if l.pool_k > 0:
-                        y = jax.lax.reduce_window(
-                            y, -jnp.inf, jax.lax.max,
-                            (1, l.pool_k, l.pool_k, 1),
-                            (1, l.pool_stride, l.pool_stride, 1), "VALID",
-                        )
-                    x = y
-                else:
-                    if x.ndim > 2:
-                        x = x.reshape(x.shape[0], -1)
-                    x = matmul(x, w)
-            return x
-
+        bm, bn, bk = self.blocks
+        forward = jax_forward(self.program, interpret=interpret,
+                              block_m=bm, block_n=bn, block_k=bk)
         ws = [jnp.asarray(w, dtype=jnp.float32) for w in self.weights]
         if self._mesh is None:
-            jit_forward = jax.jit(forward)
-            return lambda x: jit_forward(jnp.asarray(x, dtype=jnp.float32), ws)
+            return jax.jit(forward), ws, lambda x: x
 
         # sharded mode: the whole layer chain runs per batch shard inside
         # shard_map; logits gather on the ("data",) axis at the end. The
@@ -396,28 +414,42 @@ class ProgramExecutor:
         # those of the unsharded path.
         from jax.sharding import PartitionSpec as P
 
-        from repro.core import jax_compat
         from repro.parallel.sharding import leading_axis_sharding
 
         mesh = self._mesh
         n_dev = mesh.shape["data"]
-        jit_forward = jax.jit(jax_compat.shard_map(
+        jit_forward = jax.jit(jax.shard_map(
             forward, mesh=mesh, in_specs=(P("data"), P()),
-            out_specs=P("data"),
+            out_specs=P("data"), check_vma=False,
         ))
         in_sharding = leading_axis_sharding(mesh, len(self.input_shape) + 1)
 
-        def run_sharded(x):
-            x = jnp.asarray(x, dtype=jnp.float32)
-            b = x.shape[0]
-            pad = (-b) % n_dev
+        def place(x):
+            pad = (-x.shape[0]) % n_dev
             if pad:  # B need not divide the mesh: pad rows are sliced off
                 x = jnp.concatenate(
                     [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
-            x = jax.device_put(x, in_sharding)
-            return jit_forward(x, ws)[:b]
+            return jax.device_put(x, in_sharding)
 
-        return run_sharded
+        return jit_forward, ws, place
+
+    def _jax_args(self, x: np.ndarray):
+        import jax.numpy as jnp
+
+        if self._jax_chain is None:
+            self._jax_chain = self._build_jax()
+        jit_forward, ws, place = self._jax_chain
+        return jit_forward, (place(jnp.asarray(x, dtype=jnp.float32)), ws)
+
+    def lower(self, images):
+        """The jax backend's jitted chain lowered for ``images``: the
+        trace :meth:`run` dispatches for a batch of that shape. Its
+        ``.compile()`` is the executable ``run`` then reuses, with its HLO
+        text and memory analysis."""
+        if self.backend != "jax":
+            raise ValueError("lower() needs backend='jax'")
+        jit_forward, args = self._jax_args(self._batch(images))
+        return jit_forward.lower(*args)
 
     def run(self, images) -> ExecutionResult:
         """Execute the whole program on a batch of images → logits."""
@@ -426,9 +458,8 @@ class ProgramExecutor:
         if self.backend == "numpy":
             out = self._run_numpy(x)
         else:
-            if self._jax_forward is None:
-                self._jax_forward = self._build_jax()
-            out = np.asarray(self._jax_forward(x))
+            jit_forward, args = self._jax_args(x)
+            out = np.asarray(jit_forward(*args))[:x.shape[0]]
         wall = time.perf_counter() - t0
         return ExecutionResult(
             outputs=out, events=self.events, backend=self.backend,

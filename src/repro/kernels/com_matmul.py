@@ -50,8 +50,15 @@ def _kernel(x_ref, w_ref, *rest, activation, nk, has_bias, has_residual):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # float32 operands get a float32 product. At the default precision a
+    # TPU v5e multiplies them in one bfloat16 pass: 2e-3 relative error on
+    # one 576-deep matmul and 6e-3 over vgg16-imagenet's chain, where the
+    # executor holds its float32 chain to 2e-5 of the float64 oracle.
+    precision = (jax.lax.Precision.HIGHEST
+                 if x_ref.dtype == jnp.float32 else None)
     acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+        precision=precision,
     )
 
     @pl.when(k == nk - 1)

@@ -15,20 +15,23 @@ train/grad_compress.py).
 """
 from __future__ import annotations
 
-from repro.core import jax_compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax_compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, *, pod: int = 0):
     """Small mesh for tests (requires >= data*model host devices)."""
     if pod:
-        return jax_compat.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax_compat.make_mesh((data, model), ("data", "model"))
+        return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_data_mesh(devices=None):

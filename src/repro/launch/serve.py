@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import init_compile_cache
 from repro.models.transformer import CallConfig, build_model
 from repro.serve.engine import Engine, Request
 
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

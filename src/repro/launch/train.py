@@ -21,6 +21,7 @@ import numpy as np
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import init_compile_cache
 from repro.models.frontend import synth_image_embeds
 from repro.models.transformer import CallConfig, build_model
 from repro.runtime.fault_tolerance import Supervisor
@@ -44,6 +45,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

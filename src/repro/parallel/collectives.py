@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import jax_compat
 from repro.core.com import com_matmul_local, com_matmul_local_bidir
 
 
@@ -56,9 +55,9 @@ def matmul_strategy(mesh: Mesh, strategy: str, axis: str = "model"):
         out_spec = P() if strategy == "psum" else P(*([None] * (ndim - 1) + [axis]))
         if strategy == "psum":
             out_spec = P(*([None] * ndim))
-        return jax_compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
-            in_specs=(x_spec, P(axis, None)), out_specs=out_spec,
+            in_specs=(x_spec, P(axis, None)), out_specs=out_spec, check_vma=False,
         )(x, w)
 
     return mm

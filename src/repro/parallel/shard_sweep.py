@@ -70,19 +70,18 @@ def _sharded_columns_kernel(mesh):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core import jax_compat
     from repro.sweep.backend_jax import _column_exprs
 
     def kernel(chips, bits, e_mac, tpc, summary, fdm, step, eff):
         cols = _column_exprs(chips, bits, e_mac, tpc, summary, fdm, step, eff)
         return {c: jnp.broadcast_to(v, chips.shape) for c, v in cols.items()}
 
-    sharded = jax_compat.shard_map(
+    sharded = jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data"), P("data"),
                   P(), P(), P()),
-        out_specs=P("data"),
+        out_specs=P("data"), check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -98,7 +97,6 @@ def sharded_jax_backend(batch: ScenarioBatch,
     single-device mesh.
     """
     import jax
-    from jax.experimental import enable_x64
 
     from repro.launch.mesh import make_data_mesh
     from repro.parallel.sharding import leading_axis_sharding
@@ -122,7 +120,7 @@ def sharded_jax_backend(batch: ScenarioBatch,
     n = int(flat.sel.shape[0])
     chips, bits, e_mac, tpc, summary = flat_views(flat)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         f64 = lambda a: jax.numpy.asarray(a, dtype=jax.numpy.float64)  # noqa: E731
         shard = leading_axis_sharding(mesh)
         put = lambda a: jax.device_put(  # noqa: E731

@@ -13,8 +13,7 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
-
-from repro.core import jax_compat
+from jax.sharding import AxisType
 
 
 @dataclass(frozen=True)
@@ -59,4 +58,4 @@ def build_mesh(plan: MeshPlan):
         shape, names = (plan.pod, plan.data, plan.model), ("pod", "data", "model")
     else:
         shape, names = (plan.data, plan.model), ("data", "model")
-    return jax_compat.make_mesh(shape, names)
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))

@@ -12,8 +12,8 @@ reads off ONE cached ``compile_program`` call per combo (the
 Workload→CompiledProgram IR in ``repro.core.program``) — neither backend
 ever re-derives a mapping.
 
-Numerics: the kernel runs in float64 (via the ``jax.experimental
-.enable_x64`` scope, regardless of the session-wide x64 default) so it is
+Numerics: the kernel runs in float64 (via the ``jax.enable_x64(True)``
+scope, regardless of the session-wide x64 default) so it is
 golden-testable against the NumPy oracle to far better than the 1e-6 the
 tests assert.
 
@@ -29,7 +29,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.sweep.engine import COLUMNS, ScenarioBatch, register_backend
 
@@ -131,7 +130,7 @@ def flat_views(batch: ScenarioBatch):
 
 def jax_backend(batch: ScenarioBatch) -> Dict[str, np.ndarray]:
     """Evaluate a :class:`ScenarioBatch` on the jitted kernel (float64)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         f64 = lambda a: jnp.asarray(a, dtype=jnp.float64)  # noqa: E731
         if batch.sel is not None:
             # chunked mode: the batch's views gather the selected rows on

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import jax_compat
-
 PyTree = Any
 
 
@@ -63,9 +61,9 @@ def compressed_pod_psum(grads: PyTree, error: Optional[PyTree], mesh: Mesh,
         spec = P(*([None] * g.ndim))
         qspec = P(*([None] * q.ndim))
         sspec = P(*([None] * scale.ndim))
-        reduced = jax_compat.shard_map(
+        reduced = jax.shard_map(
             psum_fn, mesh=mesh,
-            in_specs=(qspec, sspec), out_specs=qspec,
+            in_specs=(qspec, sspec), out_specs=qspec, check_vma=False,
         )(q, scale)
         return reduced.reshape(g.shape).astype(g.dtype), new_e
 

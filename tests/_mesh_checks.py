@@ -6,6 +6,9 @@ Usage: python tests/_mesh_checks.py  -> exit 0 iff all checks pass.
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+# forced host devices exist only on the CPU platform; pinned, this child
+# never tries to take an accelerator that its parent may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import sys
 
@@ -14,9 +17,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from repro.core import jax_compat
 from repro.core.com import (
     com_all_gather,
     com_matmul_local,
@@ -29,13 +31,13 @@ from repro.train.grad_compress import compressed_pod_psum
 
 
 def check_com_collectives():
-    mesh = jax_compat.make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     key = jax.random.PRNGKey(0)
 
     # reduce-scatter == sum of parts
     xg = jax.random.normal(key, (64, 16, 5))
-    f = jax_compat.shard_map(lambda xp: com_reduce_scatter(xp, "model"),
-                             mesh=mesh, in_specs=P("model"), out_specs=P("model"))
+    f = jax.shard_map(lambda xp: com_reduce_scatter(xp, "model"), mesh=mesh,
+                      in_specs=P("model"), out_specs=P("model"), check_vma=False)
     out = f(xg)
     ref = xg.reshape(8, 8, 16, 5).sum(0).reshape(128, 5)
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
@@ -50,15 +52,16 @@ def check_com_collectives():
     )
 
     # bidirectional ring
-    fb = jax_compat.shard_map(lambda xl, wl: com_matmul_local_bidir(xl, wl, "model"),
-                              mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
-                              out_specs=P(None, "model"))
+    fb = jax.shard_map(lambda xl, wl: com_matmul_local_bidir(xl, wl, "model"),
+                       mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
+                       out_specs=P(None, "model"), check_vma=False)
     np.testing.assert_allclose(fb(x, w), x @ w, rtol=1e-4, atol=1e-4)
 
     # all-gather
     xa = jax.random.normal(key, (16, 3))
-    fg = jax_compat.shard_map(lambda xl: com_all_gather(xl, "model").reshape(-1, xl.shape[-1]),
-                              mesh=mesh, in_specs=P("model", None), out_specs=P(None, None))
+    fg = jax.shard_map(lambda xl: com_all_gather(xl, "model").reshape(-1, xl.shape[-1]),
+                       mesh=mesh, in_specs=P("model", None), out_specs=P(None, None),
+                       check_vma=False)
     np.testing.assert_allclose(fg(xa), xa, rtol=0, atol=0)
 
     # strategy selector: psum vs com agree
@@ -72,7 +75,7 @@ def check_com_collectives():
 
 def check_com_collective_bytes_in_hlo():
     """COM lowers to collective-permute only (no all-reduce)."""
-    mesh = jax_compat.make_mesh((8,), ("model",))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     x = jnp.ones((4, 64))
     w = jnp.ones((64, 32))
     com_mm = make_com_matmul(mesh, "model")
@@ -86,7 +89,7 @@ def check_com_collective_bytes_in_hlo():
 
 
 def check_grad_compress():
-    mesh = jax_compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(AxisType.Auto,) * 3)
     key = jax.random.PRNGKey(0)
     grads = {"a": jax.random.normal(key, (16, 8)), "b": jax.random.normal(key, (4,))}
     reduced, err = compressed_pod_psum(grads, None, mesh, axis="pod")
@@ -107,7 +110,7 @@ def check_sharded_train_step():
     from repro.train.optimizer import OptConfig, init_opt_state
     from repro.train.train_step import make_train_step
 
-    mesh = jax_compat.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("smollm-135m").reduced()
     arules = sh.act_rules(mesh, job="train")
     cc = CallConfig(dp_size=2, remat="block", shard_fn=sh.make_shard_fn(mesh, arules))
@@ -140,7 +143,7 @@ def check_elastic_remesh_restore():
     from repro.checkpoint import checkpoint as ck
     from repro.runtime.elastic import MeshPlan, build_mesh, plan_remesh
 
-    mesh_a = jax_compat.make_mesh((2, 4), ("data", "model"))
+    mesh_a = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     tree = jax.tree.map(
         lambda x: jax.device_put(x, NamedSharding(mesh_a, P("data", "model"))), tree

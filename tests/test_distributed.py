@@ -11,10 +11,8 @@ import pytest
 @pytest.mark.timeout(560)
 def test_mesh_checks_subprocess():
     script = os.path.join(os.path.dirname(__file__), "_mesh_checks.py")
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run(
-        [sys.executable, script], capture_output=True, text=True, timeout=540, env=env
+        [sys.executable, script], capture_output=True, text=True, timeout=540
     )
     sys.stdout.write(proc.stdout[-3000:])
     if proc.returncode != 0:

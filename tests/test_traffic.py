@@ -20,8 +20,6 @@ from repro.serve import (
     simulate,
 )
 
-EOS = 271  # appears organically mid-sequence in greedy smollm-reduced runs
-
 
 @pytest.fixture(scope="module")
 def served():
@@ -29,6 +27,25 @@ def served():
     model = build_model(cfg, CallConfig(remat="none"))
     params = model.init(jax.random.PRNGKey(0))
     return cfg, model, params
+
+
+@pytest.fixture(scope="module")
+def eos(served):
+    """An EOS id that greedy decoding of this reduced model emits
+    mid-sequence, read off a greedy run of the EOS test's own traffic: the
+    first token that some request emits for the first time strictly
+    inside its budget. Derived, not hard-coded, so it follows the model's
+    random init."""
+    cfg, model, params = served
+    p = eos_profile()
+    reqs = [a.request for a in generate_arrivals(p, cfg.vocab_size)]
+    eng = Engine(model, params, batch=3, max_seq=p.max_rows)
+    for r in eng.generate_sequential(reqs, seed=0):
+        toks = r.out_tokens
+        for i in range(1, len(toks) - 1):
+            if toks[i] not in toks[:i]:
+                return toks[i]
+    pytest.fail("greedy run emits no new token mid-budget")
 
 
 def profile(**over):
@@ -40,6 +57,10 @@ def profile(**over):
     )
     base.update(over)
     return TrafficProfile.from_dict(base)
+
+
+def eos_profile():
+    return profile(output_lens={"choices": [12]}, num_requests=8, seed=0)
 
 
 # -------------------- schema validation --------------------
@@ -119,7 +140,7 @@ def test_profile_lengths_bound_engine_capacity():
 # -------------------- golden determinism --------------------
 @pytest.mark.parametrize("arrival", ["poisson", "burst"])
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_traffic_serving_token_identical_to_oracle(served, arrival, seed):
+def test_traffic_serving_token_identical_to_oracle(served, eos, arrival, seed):
     """The golden contract at the serving tier: 3 seeds x 2 arrival
     profiles, EOS retirement mid-wave, paged KV, FIFO admission — every
     accepted request's tokens equal the sequential oracle's, replayed
@@ -127,7 +148,7 @@ def test_traffic_serving_token_identical_to_oracle(served, arrival, seed):
     cfg, model, params = served
     p = profile(arrival=arrival, seed=seed, burst_size=6)
     eng = Engine(model, params, batch=3, max_seq=p.max_rows,
-                 eos_id=EOS, page_size=4)
+                 eos_id=eos, page_size=4)
     payload = simulate(eng, p, policy="fifo", check=True)
     assert payload["matches_sequential"]
     assert payload["n_accepted"] == p.num_requests
@@ -137,19 +158,19 @@ def test_traffic_serving_token_identical_to_oracle(served, arrival, seed):
     assert payload["decode_steps"] > 0
 
 
-def test_eos_retirement_mid_wave(served):
+def test_eos_retirement_mid_wave(served, eos):
     """At least one request must retire on EOS before exhausting its
     budget, or the golden test above isn't exercising retirement."""
     cfg, model, params = served
-    p = profile(output_lens={"choices": [12]}, num_requests=8, seed=0)
-    eng = Engine(model, params, batch=3, max_seq=p.max_rows, eos_id=EOS)
+    p = eos_profile()
+    eng = Engine(model, params, batch=3, max_seq=p.max_rows, eos_id=eos)
     arrivals = generate_arrivals(p, cfg.vocab_size)
     queue = AdmissionQueue(arrivals, max_seq=eng.max_seq)
     done = eng.serve(queue, seed=0, do_sample=False)
     assert any(
-        len(r.out_tokens) < r.max_new_tokens and r.out_tokens[-1] == EOS
+        len(r.out_tokens) < r.max_new_tokens and r.out_tokens[-1] == eos
         for r in done
-    ), "no request hit EOS mid-budget; pick a different EOS id"
+    ), "no request hit EOS mid-budget"
     # and truncated outputs still match the oracle
     clones = [Request(prompt=a.request.prompt.copy(),
                       max_new_tokens=a.request.max_new_tokens)
@@ -159,13 +180,13 @@ def test_eos_retirement_mid_wave(served):
         assert a.request.out_tokens == c.out_tokens
 
 
-def test_latency_policy_reorders_but_tokens_match(served):
+def test_latency_policy_reorders_but_tokens_match(served, eos):
     """The latency-aware policy admits short jobs first on a burst —
     a different admission order than FIFO — yet per-request tokens stay
     oracle-identical because the key chain follows arrival indices."""
     cfg, model, params = served
     p = profile(arrival="burst", burst_size=14, output_lens={"choices": [2, 8]})
-    eng = Engine(model, params, batch=2, max_seq=p.max_rows, eos_id=EOS)
+    eng = Engine(model, params, batch=2, max_seq=p.max_rows, eos_id=eos)
     fifo = simulate(eng, p, policy="fifo", check=True)
     lat = simulate(eng, p, policy="latency", check=True)
     assert fifo["matches_sequential"] and lat["matches_sequential"]
