@@ -36,6 +36,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.mapping import ConvSpec
 from repro.core.simulator import (
     EVENT_FIELDS,
@@ -175,46 +176,58 @@ def jax_forward(program, *, interpret: bool,
                   for b in (block_m, block_n, block_k))
     layer_programs = program.layer_programs
 
-    def matmul(x2d, w2d):
+    def matmul(l, x2d, w2d):
         # one COM kernel call per layer matmul: the K-grid walks the
         # C-block chain, partial sums riding the f32 VMEM scratch;
-        # the ReLU epilogue fuses into the last K step (M-type ACT)
+        # the ReLU epilogue fuses into the last K step (M-type ACT).
+        # The kernel's name is the device op's name in a trace.
         return com_matmul_padded(
             x2d, w2d, activation="relu",
             block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+            name=f"com_matmul_{l.name}",
         )
 
     def forward(x, ws):
+        # a named scope per layer and per step of it: they change the ops'
+        # metadata only, so a device trace's ops map back to the layer
         for lp, w in zip(layer_programs, ws):
             l = lp.layer
-            if isinstance(l, ConvSpec):
-                K, P, S = l.k, l.padding, l.stride
-                Ho, Wo = l.h_out, l.w_out
-                xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
-                cols = [
-                    xp[:, kr:kr + (Ho - 1) * S + 1:S,
-                       kc:kc + (Wo - 1) * S + 1:S, :]
-                    for kr in range(K) for kc in range(K)
-                ]
-                # im2col in (kr, kc, c) order == w.reshape row-major
-                patches = jnp.concatenate(cols, axis=-1)
-                B = x.shape[0]
-                y = matmul(
-                    patches.reshape(B * Ho * Wo, K * K * l.c_in),
-                    w.reshape(K * K * l.c_in, l.c_out),
-                ).reshape(B, Ho, Wo, l.c_out)
-                if l.pool_k > 0:
-                    y = jax.lax.reduce_window(
-                        y, -jnp.inf, jax.lax.max,
-                        (1, l.pool_k, l.pool_k, 1),
-                        (1, l.pool_stride, l.pool_stride, 1), "VALID",
-                    )
-                x = y
-            else:
-                if x.ndim > 2:
-                    x = x.reshape(x.shape[0], -1)
-                x = matmul(x, w)
+            with jax.named_scope(l.name):
+                if isinstance(l, ConvSpec):
+                    x = conv(l, x, w)
+                else:
+                    if x.ndim > 2:
+                        x = x.reshape(x.shape[0], -1)
+                    with jax.named_scope("matmul"):
+                        x = matmul(l, x, w)
         return x
+
+    def conv(l, x, w):
+        K, P, S = l.k, l.padding, l.stride
+        Ho, Wo = l.h_out, l.w_out
+        B = x.shape[0]
+        with jax.named_scope("im2col"):
+            xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
+            cols = [
+                xp[:, kr:kr + (Ho - 1) * S + 1:S,
+                   kc:kc + (Wo - 1) * S + 1:S, :]
+                for kr in range(K) for kc in range(K)
+            ]
+            # im2col in (kr, kc, c) order == w.reshape row-major
+            patches = jnp.concatenate(cols, axis=-1).reshape(
+                B * Ho * Wo, K * K * l.c_in)
+        with jax.named_scope("matmul"):
+            y = matmul(
+                l, patches, w.reshape(K * K * l.c_in, l.c_out),
+            ).reshape(B, Ho, Wo, l.c_out)
+        if l.pool_k > 0:
+            with jax.named_scope("pool"):
+                y = jax.lax.reduce_window(
+                    y, -jnp.inf, jax.lax.max,
+                    (1, l.pool_k, l.pool_k, 1),
+                    (1, l.pool_stride, l.pool_stride, 1), "VALID",
+                )
+        return y
 
     return forward
 
@@ -227,12 +240,8 @@ class ExecutionResult:
     events: Mapping[str, int]    # per-image totals == network_event_totals
     backend: str
     batch: int
-    wall_s: float
+    wall_s: float                # the whole run() call, host clock
     n_shards: int = 1            # devices the batch axis was sharded over
-
-    @property
-    def images_s(self) -> float:
-        return self.batch / max(self.wall_s, 1e-12)
 
 
 class ProgramExecutor:
@@ -437,9 +446,15 @@ class ProgramExecutor:
         import jax.numpy as jnp
 
         if self._jax_chain is None:
-            self._jax_chain = self._build_jax()
+            with spans.span("executor.build") as sp:
+                self._jax_chain = self._build_jax()
+                sp.count("bytes_weights",
+                         sum(w.nbytes for w in self._jax_chain[1]))
         jit_forward, ws, place = self._jax_chain
-        return jit_forward, (place(jnp.asarray(x, dtype=jnp.float32)), ws)
+        with spans.span("executor.upload") as sp:
+            xd = place(jnp.asarray(x, dtype=jnp.float32))
+            sp.count("bytes_up", xd.nbytes)
+        return jit_forward, (xd, ws)
 
     def lower(self, images):
         """The jax backend's jitted chain lowered for ``images``: the
@@ -452,18 +467,36 @@ class ProgramExecutor:
         return jit_forward.lower(*args)
 
     def run(self, images) -> ExecutionResult:
-        """Execute the whole program on a batch of images → logits."""
-        x = self._batch(images)
-        t0 = time.perf_counter()
-        if self.backend == "numpy":
-            out = self._run_numpy(x)
-        else:
-            jit_forward, args = self._jax_args(x)
-            out = np.asarray(jit_forward(*args))[:x.shape[0]]
-        wall = time.perf_counter() - t0
+        """Execute the whole program on a batch of images → logits.
+
+        With :mod:`repro.core.spans` recording, each call is a root span
+        ``executor.run`` over ``executor.batch`` (the float64 conversion),
+        then, on the jax backend, ``executor.build`` (first call only),
+        ``executor.upload``, ``executor.dispatch`` (until the jitted chain
+        returns) and ``executor.fetch`` (the logits to the host); the
+        numpy backend's chain is ``executor.dispatch`` alone."""
+        t0 = time.perf_counter_ns()
+        with spans.span("executor.run", t0) as call:
+            with spans.span("executor.batch") as sp:
+                x = self._batch(images)
+                sp.count("bytes_host", x.nbytes)
+            if self.backend == "numpy":
+                with spans.span("executor.dispatch"):
+                    out = self._run_numpy(x)
+            else:
+                jit_forward, args = self._jax_args(x)
+                with spans.span("executor.dispatch"):
+                    y = jit_forward(*args)
+                with spans.span("executor.fetch") as sp:
+                    host = np.asarray(y)
+                    sp.count("bytes_down", host.nbytes)
+                    out = host[:x.shape[0]]
+            call.count("images", x.shape[0])
+        t1 = call.end_ns if call.end_ns is not None else time.perf_counter_ns()
         return ExecutionResult(
             outputs=out, events=self.events, backend=self.backend,
-            batch=x.shape[0], wall_s=wall, n_shards=self.n_shards,
+            batch=x.shape[0], wall_s=(t1 - t0) * 1e-9,
+            n_shards=self.n_shards,
         )
 
     def __call__(self, images) -> np.ndarray:

@@ -84,8 +84,10 @@ def com_matmul(
     block_n: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jnp.ndarray:
-    """x: (M, K), w: (K, N) -> (M, N) with fused epilogue."""
+    """x: (M, K), w: (K, N) -> (M, N) with fused epilogue. ``name`` names
+    the kernel, and so its op in the compiled HLO and a device trace."""
     M, K = x.shape
     K2, N = w.shape
     assert K == K2
@@ -120,6 +122,7 @@ def com_matmul(
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -137,6 +140,7 @@ def com_matmul_padded(
     block_n: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    name: Optional[str] = None,
 ) -> jnp.ndarray:
     """:func:`com_matmul` for arbitrary (unaligned) shapes.
 
@@ -152,15 +156,21 @@ def com_matmul_padded(
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     Mp, Kp, Np = _round_up(M, block_m), _round_up(K, block_k), _round_up(N, block_n)
-    xp = jnp.pad(x, ((0, Mp - M), (0, Kp - K))) if (Mp, Kp) != (M, K) else x
-    wp = jnp.pad(w, ((0, Kp - K), (0, Np - N))) if (Kp, Np) != (K, N) else w
-    bp = None
-    if bias is not None:
-        assert bias.shape == (N,)
-        bp = jnp.pad(bias, (0, Np - N)) if Np != N else bias
+    # the block padding and the slice back are scoped apart from the
+    # kernel, so that a device trace can tell them from it
+    with jax.named_scope("pad"):
+        xp = jnp.pad(x, ((0, Mp - M), (0, Kp - K))) if (Mp, Kp) != (M, K) else x
+        wp = jnp.pad(w, ((0, Kp - K), (0, Np - N))) if (Kp, Np) != (K, N) else w
+        bp = None
+        if bias is not None:
+            assert bias.shape == (N,)
+            bp = jnp.pad(bias, (0, Np - N)) if Np != N else bias
     out = com_matmul(
         xp, wp, bias=bp, activation=activation,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
-    return out[:M, :N] if (Mp, Np) != (M, N) else out
+    if (Mp, Np) == (M, N):
+        return out
+    with jax.named_scope("unpad"):
+        return out[:M, :N]

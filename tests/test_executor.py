@@ -215,3 +215,105 @@ def test_cache_stats_reports_bounded_caches():
         assert info.maxsize is not None      # every cache is bounded
         assert info.currsize <= info.maxsize
     assert stats["compile_program"].currsize >= 1
+
+
+JAX_SPANS = ["executor.run", "executor.batch", "executor.upload",
+             "executor.dispatch", "executor.fetch"]
+
+
+def test_run_emits_its_span_tree_each_call(vgg11_setup):
+    from repro.core import spans
+
+    wl, program, weights, images, _ = vgg11_setup
+    ex = program.executor(weights, backend="jax", interpret=True)
+    spans.enable()
+    try:
+        first = ex.run(images)
+        again = ex.run(images)
+    finally:
+        rec = spans.collect()
+    calls = [[s for s in rec["spans"] if s["call"] == c] for c in (0, 1)]
+    assert [s["name"] for s in calls[0]] == (
+        JAX_SPANS[:2] + ["executor.build"] + JAX_SPANS[2:])
+    assert [s["name"] for s in calls[1]] == JAX_SPANS
+    for call, res in zip(calls, (first, again)):
+        root, children = call[0], call[1:]
+        assert root["parent"] is None
+        assert all(rec["spans"][s["parent"]] is root for s in children)
+        assert root["counters"]["images"] == 2
+        # wall_s is read off the root span's own two clock reads
+        assert res.wall_s == (root["end_ns"] - root["start_ns"]) * 1e-9
+        by = {s["name"]: s["counters"] for s in children}
+        assert by["executor.batch"] == {"bytes_host": images.size * 8}
+        assert by["executor.upload"] == {"bytes_up": images.size * 4}
+        assert by["executor.fetch"] == {"bytes_down": 2 * 10 * 4}
+    # the first call of a new shape compiles, the repeat does not
+    assert calls[0][0]["counters"]["compiles"] >= 1
+    assert "compiles" not in calls[1][0]["counters"]
+    build = calls[0][2]["counters"]
+    assert build == {"bytes_weights": 4 * sum(w.size
+                                              for w in weights.values())}
+    np.testing.assert_array_equal(first.outputs, again.outputs)
+
+
+def test_numpy_backend_spans_and_untraced_wall_time():
+    from repro.core import spans
+
+    wl = _small_multiblock_workload()
+    program = compile_program(wl)
+    weights = random_weights(program, seed=4)
+    images = np.random.default_rng(5).normal(size=(2, 8, 8, 3))
+    ex = program.executor(weights)
+    untraced = ex.run(images)
+    assert untraced.wall_s > 0
+    spans.enable()
+    try:
+        traced = ex.run(images)
+    finally:
+        rec = spans.collect()
+    assert [s["name"] for s in rec["spans"]] == [
+        "executor.run", "executor.batch", "executor.dispatch"]
+    np.testing.assert_array_equal(traced.outputs, untraced.outputs)
+
+
+def test_every_layer_is_scoped_in_the_compiled_chain(vgg11_setup):
+    import re
+
+    wl, program, weights, images, _ = vgg11_setup
+    ex = program.executor(weights, backend="jax", interpret=True)
+    text = ex.lower(images).compile().as_text()
+    scopes = set(re.findall(r'op_name="jit\(forward\)/([^"]+)"', text))
+    for l in wl.layers:
+        steps = {s.split("/")[1] for s in scopes
+                 if s.split("/")[0] == l.name and "/" in s}
+        want = {"matmul"}
+        if isinstance(l, ConvSpec):
+            want |= {"im2col"} | ({"pool"} if l.pool_k else set())
+        assert want <= steps, (l.name, steps)
+    # the block padding and the slice back, under the matmul
+    assert any("/matmul/pad/" in s for s in scopes)
+    assert any("/matmul/unpad/" in s for s in scopes)
+    # and nothing of the chain outside a layer's scope
+    assert not [s for s in scopes
+                if s.split("/")[0] not in {l.name for l in wl.layers}]
+
+
+def test_scopes_leave_the_logits_bitwise_unchanged(monkeypatch):
+    import contextlib
+
+    import jax
+
+    from repro.core.executor import jax_forward
+
+    wl = _small_multiblock_workload()
+    program = compile_program(wl)
+    weights = random_weights(program, seed=6)
+    ws = [jax.numpy.asarray(weights[l.name], dtype=np.float32)
+          for l in wl.layers]
+    x = jax.numpy.asarray(np.random.default_rng(7).normal(size=(3, 8, 8, 3)),
+                          dtype=np.float32)
+    scoped = np.asarray(jax.jit(jax_forward(program, interpret=True))(x, ws))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = np.asarray(jax.jit(jax_forward(program, interpret=True))(x, ws))
+    np.testing.assert_array_equal(scoped, bare)
