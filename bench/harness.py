@@ -223,8 +223,12 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, t0: float, *,
 
     images = len(win.outputs) * cell.images_per_call
     if trace:
+        t_reduce = time.perf_counter()
         reduced = trace_mod.reduce_dir(tmp.name, win.wall, win.wall_calls)
         tmp.cleanup()
+        say(f"trace: {reduced.op_count} device ops in "
+            f"{len(win.wall_calls)} calls reduced in "
+            f"{time.perf_counter() - t_reduce:.3f} s")
         record = {
             "images": images, "calls": len(win.outputs),
             "window_s": win.seconds, "cfg": cfg, "mix": mix,

@@ -6,9 +6,14 @@ vgg11-cifar at batch 2 before the window, then a window of four calls.
 ``data/vgg11-cifar-b2.json`` holds the wall-clock nanoseconds of that
 window and of each call, as the harness recorded them. The source paths
 that the trace names are replaced by ``<checkout>/``, with their lengths
-kept.
+kept. ``data/vgg11-cifar-b2-spans.*`` is the same with the program's spans
+on, two calls in its window.
 """
+import bisect
 import json
+import random
+import time
+from collections import Counter
 from pathlib import Path
 
 import pytest
@@ -17,6 +22,66 @@ from bench import trace
 
 DATA = Path(__file__).resolve().parent / "data"
 KERNELS_PER_CALL = 11          # one com_matmul per vgg11 layer
+FIXTURES = ("vgg11-cifar-b2", "vgg11-cifar-b2-spans")
+
+
+def idle_by_host_reference(busy, calls, lo, hi):
+    """The idle attribution as first written: every call clipped against
+    every busy interval, so its cost grows with calls times intervals."""
+    gaps = Counter()
+    starts = [b[0] for b in busy]
+    covered = 0.0
+    for cs, ce in calls:
+        cs, ce = max(cs, lo), min(ce, hi)
+        if ce <= cs:
+            continue
+        covered += ce - cs
+        i = bisect.bisect_left(starts, cs)
+        if i > 0 and busy[i - 1][1] > cs:
+            i -= 1
+        inside = [(max(s, cs), min(e, ce)) for s, e in busy[i:]
+                  if s < ce]
+        if not inside:
+            gaps["call without device work"] += ce - cs
+            continue
+        gaps["call: before its first device op"] += inside[0][0] - cs
+        gaps["call: after its last device op"] += ce - inside[-1][1]
+        for (_, e0), (s1, _) in zip(inside, inside[1:]):
+            gaps["call: between its device ops"] += s1 - e0
+    busy_in_calls = sum(trace.clip(b, cs, ce) for cs, ce in calls
+                        for b in busy)
+    busy_all = sum(trace.clip(b, lo, hi) for b in busy)
+    gaps["between calls"] += (hi - lo - covered) - (busy_all - busy_in_calls)
+    return {k: v * 1e-9 for k, v in gaps.items() if v > 0}
+
+
+def made_up_window(seed):
+    """A window, sorted calls and merged busy intervals drawn from
+    ``seed``: calls that straddle the window's edges or hold no device
+    work, busy intervals across call edges and outside the window, now
+    and then no busy interval at all, and on every third seed times with
+    a fraction of a nanosecond, tens of seconds into the profile, as the
+    trace's event times are."""
+    rng = random.Random(seed)
+    frac = seed % 3 == 1
+    base = 4e10 if frac else 0
+    lo = base + rng.randint(0, 1000)
+    hi = lo + rng.randint(1, 5000)
+
+    def at(a, b):
+        return rng.uniform(a, b) if frac else rng.randint(int(a), int(b))
+
+    points = sorted(at(lo - 600, hi + 600)
+                    for _ in range(2 * rng.randint(0, 25)))
+    calls = list(zip(points[::2], points[1::2]))
+    if seed % 7 == 3 and calls:                 # a call over the whole window
+        calls = sorted(calls + [(lo - 10, hi + 10)])
+    busy = []
+    if seed % 10 != 0:
+        for _ in range(rng.randint(1, 80)):
+            s = at(lo - 800, hi + 800)
+            busy.append((s, s + at(1, 200)))
+    return trace.merge(busy), calls, lo, hi
 
 
 @pytest.fixture(scope="module")
@@ -88,6 +153,41 @@ def test_idle_attribution_on_made_up_intervals():
         "call: after its last device op": 10e-9,
         "between calls": 25e-9,
     })
+
+
+@pytest.mark.parametrize("seed", range(240))
+def test_idle_attribution_matches_the_reference(seed):
+    busy, calls, lo, hi = made_up_window(seed)
+    assert trace._idle_by_host(busy, calls, lo, hi) == (
+        idle_by_host_reference(busy, calls, lo, hi))
+
+
+@pytest.mark.parametrize("name", FIXTURES)
+def test_recorded_traces_reduce_as_the_reference_does(name, monkeypatch):
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(str(DATA / f"{name}.xplane.pb"))
+    host = json.loads((DATA / f"{name}.json").read_text())
+    got = trace.reduce_profile(data, host["window"], host["calls"])
+    monkeypatch.setattr(trace, "_idle_by_host", idle_by_host_reference)
+    want = trace.reduce_profile(data, host["window"], host["calls"])
+    assert got.gaps and got.gaps == want.gaps
+    assert got.breakdown() == want.breakdown()
+
+
+@pytest.mark.timeout(60)
+def test_idle_attribution_grows_with_calls_plus_intervals():
+    # 3,000 back-to-back calls of 1 ms, each with 300 device ops of 2 us
+    # and a gap of 1 us after each: about twice a 20 s b1 window on a v5e
+    calls = [(t * 1_000_000, t * 1_000_000 + 990_000) for t in range(3000)]
+    busy = [(c + 10_000 + 3_000 * k, c + 12_000 + 3_000 * k)
+            for c, _ in calls for k in range(300)]
+    t0 = time.perf_counter()
+    gaps = trace._idle_by_host(busy, calls, 0, 3000 * 1_000_000)
+    assert time.perf_counter() - t0 < 5
+    assert gaps["call: between its device ops"] == pytest.approx(
+        3000 * 299 * 1e-6)
+    assert gaps["between calls"] == pytest.approx(3000 * 10e-6)
 
 
 def test_merge_and_labels():
