@@ -30,9 +30,10 @@ uses), and a full program run's totals equal ``network_event_totals``.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -46,6 +47,9 @@ from repro.core.simulator import (
     run_conv_block_chain,
     run_fc_block_chain,
 )
+
+if TYPE_CHECKING:
+    from repro.kernels.com_matmul import MatmulPlan
 
 BACKENDS: Tuple[str, ...] = ("numpy", "jax")
 
@@ -152,57 +156,62 @@ def _maxpool_np(x: np.ndarray, k: int, s: int) -> np.ndarray:
 
 def jax_forward(program, *, interpret: bool,
                 block_m: Optional[int] = None, block_n: Optional[int] = None,
-                block_k: Optional[int] = None):
+                block_k: Optional[int] = None, images: Optional[int] = None):
     """The jax backend's whole layer chain as one pure function.
 
     ``forward(x, ws)`` maps float32 images ``(B, *input_shape)`` and the
     float32 weights ``ws`` (one per layer, in layer order) to logits, with
-    one Pallas ``com_matmul`` call per conv/FC layer. The executor jits
-    it (:meth:`ProgramExecutor.lower`); compiling it yourself from
-    abstract inputs (``jax.jit(forward).lower(x, ws).compile()``) gives
-    the executable's HLO text and memory analysis for a described TPU.
+    one Pallas ``com_matmul`` call per conv/FC layer. ``block_*`` fix that
+    block dimension of every kernel; the others are chosen per layer from
+    its matmul's shape (:meth:`ProgramExecutor.plan` lists them): the
+    shape of the batch ``forward`` is called with, or of a batch of
+    ``images`` where that is given. The sharded executor gives the whole
+    batch, so that each shard runs the one-device chain's blocks and its
+    logits are bitwise the one-device ones. The executor jits it
+    (:meth:`ProgramExecutor.lower`); compiling it yourself from abstract
+    inputs (``jax.jit(forward).lower(x, ws).compile()``) gives the
+    executable's HLO text and memory analysis for a described TPU.
     """
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.com_matmul import com_matmul_padded
 
-    # MXU-aligned 128 blocks on real TPUs; interpret mode unrolls the
-    # grid into the jitted graph, so bigger blocks (fewer, larger
-    # dots) are what make the CPU CI path fast — 512³ blocks run a
-    # B=32 VGG-11 chain faster than the batched NumPy oracle.
-    default_block = 512 if interpret else 128
-    bm, bn, bk = (b if b is not None else default_block
-                  for b in (block_m, block_n, block_k))
     layer_programs = program.layer_programs
+    given = dict(block_m=block_m, block_n=block_n, block_k=block_k)
+    blocks = [given] * len(layer_programs)
+    if images is not None:
+        blocks = [dict(block_m=p.bm, block_n=p.bn, block_k=p.bk)
+                  for _, p in matmul_plans(program, images, **given)]
 
-    def matmul(l, x2d, w2d):
+    def matmul(l, x2d, w2d, blk):
         # one COM kernel call per layer matmul: the K-grid walks the
         # C-block chain, partial sums riding the f32 VMEM scratch;
         # the ReLU epilogue fuses into the last K step (M-type ACT).
+        # Blocks not given are chosen from the matmul's shape
+        # (``choose_blocks``), on a TPU and in interpret mode alike.
         # The kernel's name is the device op's name in a trace.
         return com_matmul_padded(
-            x2d, w2d, activation="relu",
-            block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
-            name=f"com_matmul_{l.name}",
+            x2d, w2d, activation="relu", **blk,
+            interpret=interpret, name=f"com_matmul_{l.name}",
         )
 
     def forward(x, ws):
         # a named scope per layer and per step of it: they change the ops'
         # metadata only, so a device trace's ops map back to the layer
-        for lp, w in zip(layer_programs, ws):
+        for lp, w, blk in zip(layer_programs, ws, blocks):
             l = lp.layer
             with jax.named_scope(l.name):
                 if isinstance(l, ConvSpec):
-                    x = conv(l, x, w)
+                    x = conv(l, x, w, blk)
                 else:
                     if x.ndim > 2:
                         x = x.reshape(x.shape[0], -1)
                     with jax.named_scope("matmul"):
-                        x = matmul(l, x, w)
+                        x = matmul(l, x, w, blk)
         return x
 
-    def conv(l, x, w):
+    def conv(l, x, w, blk):
         K, P, S = l.k, l.padding, l.stride
         Ho, Wo = l.h_out, l.w_out
         B = x.shape[0]
@@ -218,7 +227,7 @@ def jax_forward(program, *, interpret: bool,
                 B * Ho * Wo, K * K * l.c_in)
         with jax.named_scope("matmul"):
             y = matmul(
-                l, patches, w.reshape(K * K * l.c_in, l.c_out),
+                l, patches, w.reshape(K * K * l.c_in, l.c_out), blk,
             ).reshape(B, Ho, Wo, l.c_out)
         if l.pool_k > 0:
             with jax.named_scope("pool"):
@@ -230,6 +239,31 @@ def jax_forward(program, *, interpret: bool,
         return y
 
     return forward
+
+
+def matmul_plans(program, images: int, *, block_m: Optional[int] = None,
+                 block_n: Optional[int] = None, block_k: Optional[int] = None
+                 ) -> List[Tuple[str, "MatmulPlan"]]:
+    """``(layer name, MatmulPlan)`` for each layer's ``com_matmul_padded``
+    call in :func:`jax_forward`'s chain on a batch of ``images``: the
+    matmul's ``(M, K, N)``, its blocks, grid steps and padded bytes."""
+    from repro.kernels.com_matmul import matmul_plan
+
+    return [(lp.layer.name, matmul_plan(*_matmul_dims(lp.layer, images),
+                                        _ITEMSIZE, block_m=block_m,
+                                        block_n=block_n, block_k=block_k))
+            for lp in program.layer_programs]
+
+
+_ITEMSIZE = np.dtype(np.float32).itemsize   # the chain's dtype
+
+
+def _matmul_dims(layer, images: int) -> Tuple[int, int, int]:
+    """``(M, K, N)`` of ``layer``'s matmul on a batch of ``images``."""
+    if isinstance(layer, ConvSpec):
+        return (images * layer.h_out * layer.w_out,
+                layer.k * layer.k * layer.c_in, layer.c_out)
+    return images, layer.c_in, layer.c_out
 
 
 @dataclass(frozen=True)
@@ -401,9 +435,10 @@ class ProgramExecutor:
 
     # ---- jax backend: block chains lowered to the Pallas COM kernel ----
     def _build_jax(self):
-        """``(jit_forward, ws, place)``: the jitted chain, its float32
-        weights on the device, and the map from a float32 batch to the
-        chain's input (padded and sharded in sharded mode)."""
+        """``(chain, ws, place)``: ``chain(b)``, the jitted chain for a
+        batch of ``b`` images, its float32 weights on the device, and the
+        map from a float32 batch to the chain's input (padded and sharded
+        in sharded mode)."""
         import jax
         import jax.numpy as jnp
 
@@ -411,26 +446,34 @@ class ProgramExecutor:
         if interpret is None:
             interpret = default_interpret()
         bm, bn, bk = self.blocks
-        forward = jax_forward(self.program, interpret=interpret,
-                              block_m=bm, block_n=bn, block_k=bk)
         ws = [jnp.asarray(w, dtype=jnp.float32) for w in self.weights]
         if self._mesh is None:
-            return jax.jit(forward), ws, lambda x: x
+            jit_forward = jax.jit(jax_forward(
+                self.program, interpret=interpret,
+                block_m=bm, block_n=bn, block_k=bk))
+            return lambda images: jit_forward, ws, lambda x: x
 
         # sharded mode: the whole layer chain runs per batch shard inside
         # shard_map; logits gather on the ("data",) axis at the end. The
-        # chain has no cross-image math, so per-image results are bitwise
-        # those of the unsharded path.
+        # chain has no cross-image math, and each shard runs the blocks
+        # chosen for the whole batch, as the unsharded path does, so
+        # per-image results are bitwise those of the unsharded path.
         from jax.sharding import PartitionSpec as P
 
         from repro.parallel.sharding import leading_axis_sharding
 
         mesh = self._mesh
         n_dev = mesh.shape["data"]
-        jit_forward = jax.jit(jax.shard_map(
-            forward, mesh=mesh, in_specs=(P("data"), P()),
-            out_specs=P("data"), check_vma=False,
-        ))
+
+        @functools.lru_cache(maxsize=None)
+        def chain(images):
+            return jax.jit(jax.shard_map(
+                jax_forward(self.program, interpret=interpret, block_m=bm,
+                            block_n=bn, block_k=bk, images=images),
+                mesh=mesh, in_specs=(P("data"), P()),
+                out_specs=P("data"), check_vma=False,
+            ))
+
         in_sharding = leading_axis_sharding(mesh, len(self.input_shape) + 1)
 
         def place(x):
@@ -440,7 +483,23 @@ class ProgramExecutor:
                     [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)])
             return jax.device_put(x, in_sharding)
 
-        return jit_forward, ws, place
+        return chain, ws, place
+
+    def plan(self, batch: int) -> List[Tuple[str, "MatmulPlan"]]:
+        """:func:`matmul_plans` of the jax backend's chain for a batch of
+        ``batch`` images, as each device runs it: in sharded mode, its
+        share of the batch on the blocks chosen for the whole batch."""
+        from repro.kernels.com_matmul import matmul_plan
+
+        bm, bn, bk = self.blocks
+        whole = matmul_plans(self.program, batch,
+                             block_m=bm, block_n=bn, block_k=bk)
+        if self.n_shards == 1:
+            return whole
+        rows = -(-batch // self.n_shards)   # images on each device
+        return [(name, matmul_plan(*_matmul_dims(lp.layer, rows), _ITEMSIZE,
+                                   block_m=p.bm, block_n=p.bn, block_k=p.bk))
+                for (name, p), lp in zip(whole, self.program.layer_programs)]
 
     def _jax_args(self, x: np.ndarray):
         import jax.numpy as jnp
@@ -450,7 +509,12 @@ class ProgramExecutor:
                 self._jax_chain = self._build_jax()
                 sp.count("bytes_weights",
                          sum(w.nbytes for w in self._jax_chain[1]))
-        jit_forward, ws, place = self._jax_chain
+                plan = [p for _, p in self.plan(x.shape[0])]
+                sp.count("grid_steps", sum(p.steps for p in plan))
+                sp.count("pad_bytes_per_call",
+                         sum(p.pad_bytes for p in plan))
+        chain, ws, place = self._jax_chain
+        jit_forward = chain(x.shape[0])
         with spans.span("executor.upload") as sp:
             xd = place(jnp.asarray(x, dtype=jnp.float32))
             sp.count("bytes_up", xd.nbytes)
@@ -471,7 +535,10 @@ class ProgramExecutor:
 
         With :mod:`repro.core.spans` recording, each call is a root span
         ``executor.run`` over ``executor.batch`` (the float64 conversion),
-        then, on the jax backend, ``executor.build`` (first call only),
+        then, on the jax backend, ``executor.build`` (first call only;
+        besides ``bytes_weights`` it counts, from :meth:`plan` for that
+        call's batch, the kernels' ``grid_steps`` on each device and the
+        ``pad_bytes_per_call`` of their padded operands),
         ``executor.upload``, ``executor.dispatch`` (until the jitted chain
         returns) and ``executor.fetch`` (the logits to the host); the
         numpy backend's chain is ``executor.dispatch`` alone."""

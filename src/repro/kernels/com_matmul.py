@@ -7,13 +7,19 @@ to HBM), and the epilogue (Add=bias, Act=relu/silu/gelu, Bp=residual) is
 applied on the LAST K step before the single HBM writeback — computing on
 the move instead of a separate elementwise pass over HBM.
 
-Block shapes default to MXU-aligned (128 multiples); VMEM working set =
-bm*bk + bk*bn (bf16) + bm*bn (f32 acc) — sized well under 16MB v5e VMEM.
+:func:`com_matmul` takes its blocks as given (128 by default).
+:func:`com_matmul_padded` takes each block it is not given from
+:func:`choose_blocks`, which reads the matmul's ``(M, K, N)`` and dtype:
+among block shapes that Mosaic accepts (a multiple of the tile, 8 rows or
+128 lanes for 4-byte types, or the whole dimension) and whose
+double-buffered working set fits the VMEM budget, it takes those that
+divide their dimensions, so that nothing is padded, then the fewest grid
+steps, then the fewest bytes re-read from HBM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -130,21 +136,140 @@ def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
 
 
+#: what :func:`vmem_bytes` may come to: Mosaic's default scoped VMEM limit
+#: on a TPU v5e is 16 MiB, a kernel that asks for more fails to compile,
+#: and the rest is margin
+VMEM_BUDGET_BYTES = 12 * 2**20
+LANES = 128
+
+
+def _sublanes(itemsize: int) -> int:
+    """Rows of one VMEM tile: 8 for 4-byte types, 16 for 2-byte ones."""
+    return 8 * max(1, 4 // itemsize)
+
+
+def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int) -> int:
+    """Scoped VMEM of a :func:`com_matmul` on blocks ``(bm, bk) @ (bk,
+    bn)``, each rounded up to whole tiles as Mosaic lays them out: x, w and
+    the output double-buffered, the float32 accumulator, and the scratch
+    Mosaic adds for the product. That scratch was read off the v5e
+    compiler (the least ``vmem_limit_bytes`` that compiles, float32
+    operands at ``HIGHEST``): up to four float32 ``(bm, 128)`` stripes,
+    and four times the x block where the output is wider than one lane
+    tile.
+    """
+    sub = _sublanes(itemsize)
+
+    def tiles(rows, cols, size, rows_tile):
+        return _round_up(rows, rows_tile) * _round_up(cols, LANES) * size
+
+    x = tiles(bm, bk, itemsize, sub)
+    buffers = 2 * (x + tiles(bk, bn, itemsize, sub)
+                   + tiles(bm, bn, itemsize, sub)) + tiles(bm, bn, 4, 8)
+    scratch = 4 * tiles(bm, LANES, 4, 8)
+    if _round_up(bn, LANES) > LANES:
+        scratch += 4 * x
+    return buffers + scratch
+
+
+def _divisors(n: int) -> List[int]:
+    small = [d for d in range(1, int(n ** 0.5) + 1) if n % d == 0]
+    return sorted(set(small + [n // d for d in small]))
+
+
+def _block_candidates(dim: int, tile: int, given: Optional[int]) -> List[int]:
+    """The blocks one dimension may take: ``given`` alone where the caller
+    gave it; else the whole dimension and every multiple of ``tile`` that
+    divides it, and, where ``dim`` is no multiple of ``tile``, the
+    multiples of ``tile`` that divide it rounded up to one (these pad)."""
+    if given is not None:
+        return [given]
+    out = {dim} | {b for b in _divisors(dim) if b % tile == 0}
+    if dim % tile:
+        padded = _round_up(dim, tile)
+        out |= {b for b in _divisors(padded) if b % tile == 0}
+    return sorted(out)
+
+
+@functools.lru_cache(maxsize=1024)
+def choose_blocks(M: int, K: int, N: int, itemsize: int, *,
+                  block_m: Optional[int] = None,
+                  block_n: Optional[int] = None,
+                  block_k: Optional[int] = None) -> Tuple[int, int, int]:
+    """``(bm, bn, bk)`` for an ``(M, K) @ (K, N)`` :func:`com_matmul` of
+    ``itemsize``-byte operands; a block given is kept, and the others are
+    chosen around it.
+
+    Each block is the whole dimension or a multiple of its tile (rows of
+    x and the output: 8 for 4-byte types; K and N: 128 lanes). Among
+    them the rule takes, in this order: a working set
+    (:func:`vmem_bytes`) within ``VMEM_BUDGET_BYTES``; blocks that divide
+    their dimensions, so that :func:`com_matmul_padded` pads nothing;
+    the fewest grid steps; the fewest bytes read from HBM, x being read
+    ``N / bn`` times and w ``M / bm`` times.
+    """
+    sub = _sublanes(itemsize)
+    best, best_key = None, None
+    for bm in _block_candidates(M, sub, block_m):
+        for bn in _block_candidates(N, LANES, block_n):
+            for bk in _block_candidates(K, LANES, block_k):
+                Mp, Np, Kp = (_round_up(M, bm), _round_up(N, bn),
+                              _round_up(K, bk))
+                steps = (Mp // bm) * (Np // bn) * (Kp // bk)
+                reads = Mp * Kp * (Np // bn) + Kp * Np * (Mp // bm)
+                key = (max(0, vmem_bytes(bm, bk, bn, itemsize)
+                           - VMEM_BUDGET_BYTES),
+                       (Mp, Np, Kp) != (M, N, K), steps, reads, -bm)
+                if best_key is None or key < best_key:
+                    best, best_key = (bm, bn, bk), key
+    return best
+
+
+class MatmulPlan(NamedTuple):
+    """One :func:`com_matmul_padded` call as it runs: its shape, blocks,
+    grid steps, and the bytes of the padded copies it makes."""
+
+    m: int
+    k: int
+    n: int
+    bm: int
+    bn: int
+    bk: int
+    steps: int
+    pad_bytes: int
+
+
+def matmul_plan(M: int, K: int, N: int, itemsize: int, *,
+                block_m: Optional[int] = None, block_n: Optional[int] = None,
+                block_k: Optional[int] = None) -> MatmulPlan:
+    """How :func:`com_matmul_padded` runs ``(M, K) @ (K, N)`` with the
+    blocks it is given and :func:`choose_blocks` picks the rest."""
+    bm, bn, bk = choose_blocks(M, K, N, itemsize, block_m=block_m,
+                               block_n=block_n, block_k=block_k)
+    Mp, Kp, Np = _round_up(M, bm), _round_up(K, bk), _round_up(N, bn)
+    pad = ((Mp * Kp if (Mp, Kp) != (M, K) else 0)
+           + (Kp * Np if (Kp, Np) != (K, N) else 0))
+    return MatmulPlan(M, K, N, bm, bn, bk,
+                      (Mp // bm) * (Np // bn) * (Kp // bk), pad * itemsize)
+
+
 def com_matmul_padded(
     x: jnp.ndarray,
     w: jnp.ndarray,
     *,
     bias: Optional[jnp.ndarray] = None,
     activation: Optional[str] = None,
-    block_m: int = 128,
-    block_n: int = 128,
-    block_k: int = 128,
+    block_m: Optional[int] = None,
+    block_n: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
     name: Optional[str] = None,
 ) -> jnp.ndarray:
     """:func:`com_matmul` for arbitrary (unaligned) shapes.
 
-    Zero-pads every dimension up to the next block multiple, runs the
+    Blocks not given come from :func:`choose_blocks` (see
+    :func:`matmul_plan`). Where a block does not divide its dimension,
+    zero-pads that dimension up to the next block multiple, runs the
     kernel, and slices the result back to ``(M, N)``. Zero K-padding adds
     zeros into the VMEM partial-sum accumulation (exact); padded M rows /
     N cols are sliced away before the caller sees them, so the epilogue
@@ -155,7 +280,10 @@ def com_matmul_padded(
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
-    Mp, Kp, Np = _round_up(M, block_m), _round_up(K, block_k), _round_up(N, block_n)
+    plan = matmul_plan(M, K, N, x.dtype.itemsize, block_m=block_m,
+                       block_n=block_n, block_k=block_k)
+    Mp, Kp, Np = (_round_up(M, plan.bm), _round_up(K, plan.bk),
+                  _round_up(N, plan.bn))
     # the block padding and the slice back are scoped apart from the
     # kernel, so that a device trace can tell them from it
     with jax.named_scope("pad"):
@@ -167,7 +295,7 @@ def com_matmul_padded(
             bp = jnp.pad(bias, (0, Np - N)) if Np != N else bias
     out = com_matmul(
         xp, wp, bias=bp, activation=activation,
-        block_m=block_m, block_n=block_n, block_k=block_k,
+        block_m=plan.bm, block_n=plan.bn, block_k=plan.bk,
         interpret=interpret, name=name,
     )
     if (Mp, Np) == (M, N):

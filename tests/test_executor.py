@@ -251,8 +251,11 @@ def test_run_emits_its_span_tree_each_call(vgg11_setup):
     assert calls[0][0]["counters"]["compiles"] >= 1
     assert "compiles" not in calls[1][0]["counters"]
     build = calls[0][2]["counters"]
+    plan = [p for _, p in ex.plan(len(images))]
     assert build == {"bytes_weights": 4 * sum(w.size
-                                              for w in weights.values())}
+                                              for w in weights.values()),
+                     "grid_steps": sum(p.steps for p in plan),
+                     "pad_bytes_per_call": sum(p.pad_bytes for p in plan)}
     np.testing.assert_array_equal(first.outputs, again.outputs)
 
 
@@ -290,12 +293,20 @@ def test_every_layer_is_scoped_in_the_compiled_chain(vgg11_setup):
         if isinstance(l, ConvSpec):
             want |= {"im2col"} | ({"pool"} if l.pool_k else set())
         assert want <= steps, (l.name, steps)
-    # the block padding and the slice back, under the matmul
-    assert any("/matmul/pad/" in s for s in scopes)
-    assert any("/matmul/unpad/" in s for s in scopes)
-    # and nothing of the chain outside a layer's scope
+    # nothing of the chain outside a layer's scope
     assert not [s for s in scopes
                 if s.split("/")[0] not in {l.name for l in wl.layers}]
+    # the chosen blocks divide every matmul: nothing is padded
+    assert not any("/matmul/pad/" in s or "/matmul/unpad/" in s
+                   for s in scopes)
+    # blocks given that do not divide: the block padding and the slice
+    # back, under the matmul
+    padded = program.executor(weights, backend="jax", interpret=True,
+                              block_m=128, block_n=128, block_k=128)
+    scopes = set(re.findall(r'op_name="jit\(forward\)/([^"]+)"',
+                            padded.lower(images).compile().as_text()))
+    assert any("/matmul/pad/" in s for s in scopes)
+    assert any("/matmul/unpad/" in s for s in scopes)
 
 
 def test_scopes_leave_the_logits_bitwise_unchanged(monkeypatch):
@@ -317,3 +328,90 @@ def test_scopes_leave_the_logits_bitwise_unchanged(monkeypatch):
                         lambda name: contextlib.nullcontext())
     bare = np.asarray(jax.jit(jax_forward(program, interpret=True))(x, ws))
     np.testing.assert_array_equal(scoped, bare)
+
+
+def _chain_kernels(program, images, **blocks):
+    """``(kernel name, grid, x block shape)`` of each Pallas call in the
+    jax backend's chain for ``images``, read off its jaxpr."""
+    import jax
+
+    from repro.core.executor import jax_forward
+
+    layers = program.workload.layers
+    ws = [jax.ShapeDtypeStruct(
+        (l.k, l.k, l.c_in, l.c_out) if isinstance(l, ConvSpec)
+        else (l.c_in, l.c_out), np.float32) for l in layers]
+    x = jax.ShapeDtypeStruct(images.shape, np.float32)
+    eqns = jax.make_jaxpr(jax_forward(program, interpret=True, **blocks))(
+        x, ws).jaxpr.eqns
+    return [(e.params["name"], tuple(e.params["grid_mapping"].grid),
+             tuple(b.block_size for b in
+                   e.params["grid_mapping"].block_mappings[0].block_shape))
+            for e in eqns if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("blocks", [{}, {"block_m": 8},
+                                    {"block_m": 128, "block_n": 128,
+                                     "block_k": 128}],
+                         ids=["chosen", "block_m", "given"])
+def test_plan_is_what_the_chain_runs(vgg11_setup, blocks):
+    wl, program, weights, images, _ = vgg11_setup
+    ex = program.executor(weights, backend="jax", interpret=True, **blocks)
+    plan = ex.plan(len(images))
+    assert [name for name, _ in plan] == [l.name for l in wl.layers]
+    want = []
+    for name, p in plan:
+        grid = (-(-p.m // p.bm), -(-p.n // p.bn), -(-p.k // p.bk))
+        assert grid[0] * grid[1] * grid[2] == p.steps
+        want.append((f"com_matmul_{name}", grid, (p.bm, p.bk)))
+    assert _chain_kernels(program, images, **blocks) == want
+
+
+def test_build_counts_the_plans_grid_steps_and_pad_bytes():
+    from repro.core import spans
+
+    program = compile_program(_small_multiblock_workload())
+    weights = random_weights(program, seed=8)
+    images = np.random.default_rng(9).normal(size=(3, 8, 8, 3))
+    builds = {}
+    for kind, blocks in (("chosen", {}),
+                         ("given", dict(block_m=16, block_n=128,
+                                        block_k=128))):
+        ex = program.executor(weights, backend="jax", interpret=True,
+                              **blocks)
+        spans.enable()
+        try:
+            out = ex.run(images).outputs
+        finally:
+            rec = spans.collect()
+        plan = [p for _, p in ex.plan(len(images))]
+        build, = [s for s in rec["spans"] if s["name"] == "executor.build"]
+        assert build["counters"]["grid_steps"] == sum(p.steps for p in plan)
+        builds[kind] = (build["counters"], plan, out)
+    counters, plan, chosen_out = builds["chosen"]
+    # every shape of this chain divides into its chosen blocks
+    assert counters["pad_bytes_per_call"] == 0
+    assert all(p.pad_bytes == 0 for p in plan)
+    # 16-row, 128-lane blocks pad all four matmuls of 3 images
+    counters, plan, given_out = builds["given"]
+    assert counters["pad_bytes_per_call"] == sum(p.pad_bytes for p in plan)
+    assert all(p.pad_bytes > 0 for p in plan)
+    np.testing.assert_allclose(given_out, chosen_out, rtol=1e-5, atol=1e-6)
+
+
+def test_plan_gives_each_device_its_share_of_the_batch(monkeypatch):
+    from repro.core.executor import matmul_plans
+    from repro.kernels.com_matmul import matmul_plan
+
+    program = compile_program(_small_multiblock_workload())
+    ex = program.executor(random_weights(program, seed=1), backend="jax",
+                          interpret=True)
+    assert ex.plan(8) == matmul_plans(program, 8)
+    monkeypatch.setattr(ProgramExecutor, "n_shards", property(lambda s: 4))
+    for batch, rows in ((8, 2), (9, 3)):    # 9 is padded to 12 images
+        whole = matmul_plans(program, batch)
+        # each device runs its rows on the whole batch's blocks
+        assert ex.plan(batch) == [
+            (name, matmul_plan(p.m // batch * rows, p.k, p.n, 4,
+                               block_m=p.bm, block_n=p.bn, block_k=p.bk))
+            for name, p in whole]

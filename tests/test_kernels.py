@@ -10,7 +10,7 @@ except ModuleNotFoundError:  # stripped container: deterministic fallback
     from _hypothesis_stub import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.com_matmul import com_matmul
+from repro.kernels.com_matmul import com_matmul, com_matmul_padded, matmul_plan
 from repro.kernels.conv2d_com import conv2d_com
 from repro.kernels.flash_attention import flash_attention, flash_attention_gqa
 
@@ -39,6 +39,66 @@ def test_com_matmul_sweep(m, n, k, bm, dtype, act):
         y.astype(np.float32), yr.astype(np.float32),
         rtol=rtol_for(dtype), atol=k * (0.05 if dtype == jnp.bfloat16 else 1e-4),
     )
+
+
+def _pallas_grids(fn, *args):
+    """``(grid, x block shape)`` of each ``pallas_call`` that ``fn`` traces."""
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    return [(tuple(e.params["grid_mapping"].grid),
+             tuple(b.block_size for b in
+                   e.params["grid_mapping"].block_mappings[0].block_shape))
+            for e in eqns if e.primitive.name == "pallas_call"]
+
+
+def _relu_dot(x, w):
+    return jnp.maximum(
+        jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST), 0.0)
+
+
+@pytest.mark.parametrize("m, k, n, split_k", [
+    (1, 4096, 1000, True),     # M = 1 (an FC layer at batch 1), N = 1000
+    (196, 4608, 512, True),    # M = 196 whole (vgg16's conv10 at batch 1)
+    (3136, 27, 64, False),     # K = 27 whole (conv0), N = 64 whole
+    (1024, 576, 64, False),    # K = 576 whole (conv1)
+    (256, 4096, 10, True),     # N = 10 whole (vgg11-cifar's logits)
+], ids=["m1", "m196", "k27", "k576", "n10"])
+def test_com_matmul_padded_chosen_blocks(m, k, n, split_k):
+    """The chosen blocks, as the executor runs them, against ``jnp.dot``
+    at ``HIGHEST``; K split into blocks that divide it where ``split_k``."""
+    plan = matmul_plan(m, k, n, 4)
+    assert plan.pad_bytes == 0
+    assert (plan.bk < k) == split_k, plan
+    key = jax.random.PRNGKey(m + k + n)
+    x = jax.random.normal(key, (m, k), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (k, n), jnp.float32)
+
+    def fn(x, w):
+        return com_matmul_padded(x, w, activation="relu", interpret=True)
+
+    assert _pallas_grids(fn, x, w) == [
+        ((m // plan.bm, n // plan.bn, k // plan.bk), (plan.bm, plan.bk))]
+    y, ref = fn(x, w), _relu_dot(x, w)
+    np.testing.assert_allclose(y, ref, rtol=2e-5,
+                               atol=1e-5 * float(jnp.abs(ref).max()))
+
+
+def test_com_matmul_padded_block_m_overrides_the_chooser():
+    key = jax.random.PRNGKey(5)
+    x = jax.random.normal(key, (64, 576), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (576, 64), jnp.float32)
+    chosen = matmul_plan(64, 576, 64, 4)
+    assert (chosen.bm, chosen.bk, chosen.bn) == (64, 576, 64)
+    for block_m, grid in ((8, (8, 1, 1)), (48, (2, 1, 1))):
+
+        def fn(x, w):
+            return com_matmul_padded(x, w, activation="relu",
+                                     block_m=block_m, interpret=True)
+
+        # block_m is kept; K and N are still chosen; 48 pads M to 96
+        assert _pallas_grids(fn, x, w) == [(grid, (block_m, 576))]
+        ref = _relu_dot(x, w)
+        np.testing.assert_allclose(fn(x, w), ref, rtol=2e-5,
+                                   atol=1e-5 * float(jnp.abs(ref).max()))
 
 
 def test_com_matmul_residual_epilogue():

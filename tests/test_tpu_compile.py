@@ -5,7 +5,9 @@ for a ``v5e:2x2`` topology that is described, not attached. That checks
 what Pallas interpret mode never does (tile alignment, VMEM limits, and
 whether the program fits the chip's 16 GB of HBM) at the real widths of
 ``vgg16-imagenet`` at batch 8: one test per distinct ``com_matmul_padded``
-shape class of the chain, and one for the whole chain.
+shape class of the chain, and one for the whole chain; and the whole chain
+of each benchmark cell, whose blocks ``choose_blocks`` picks per layer
+within the kernel's default VMEM limit.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -86,3 +88,20 @@ def test_vgg16_chain_compiles_for_v5e(one_chip):
     total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
              + mem.output_size_in_bytes)
     assert total < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("network, batch", [
+    ("vgg16-imagenet", 1), ("vgg16-imagenet", 32), ("vgg11-cifar", 256),
+], ids=["vgg16-b1", "vgg16-b32", "vgg11-b256"])
+def test_cell_chain_compiles_for_v5e_unpadded(one_chip, network, batch):
+    program = compile_program(resolve_network(network))
+    layers = program.workload.layers
+    first = layers[0]
+    x = _spec((batch, first.h_in, first.w_in, first.c_in), one_chip)
+    ws = [_spec((l.k, l.k, l.c_in, l.c_out) if isinstance(l, ConvSpec)
+                else (l.c_in, l.c_out), one_chip) for l in layers]
+    text = jax.jit(jax_forward(program, interpret=False)).lower(
+        x, ws).compile().as_text()
+    assert text.count(KERNEL_CALL) == len(layers)
+    # the chosen blocks divide every matmul: no block padding, no slice
+    assert "/matmul/pad/" not in text and "/matmul/unpad/" not in text
