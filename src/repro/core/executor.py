@@ -5,10 +5,12 @@ module runs an entire :class:`~repro.core.program.CompiledProgram` end to
 end — every layer's ``ceil(C/n_c) × ceil(M/n_m)`` block chain, partial sums
 accumulated across C-blocks, outputs concatenated across M-blocks, each
 layer's OFM (after the fused M-type pooling, when present) feeding the next
-layer's IFM (conv→conv, conv→flatten→FC, FC→FC) — **batched over a leading
-image axis**, so one call simulates B images. That turns the simulator from
-a per-layer checker into a fast whole-network oracle (the paper evaluates
-whole networks, Tab. IV).
+layer's IFM (conv→conv, conv→flatten→FC, FC→FC) or the layer its
+``input_from`` names, and a residual join's shortcut (``residual_from``)
+added in the join layer's epilogue before its activation — **batched over
+a leading image axis**, so one call simulates B images. That turns the
+simulator from a per-layer checker into a fast whole-network oracle (the
+paper evaluates whole networks, Tab. IV).
 
 Two backends, mirroring the sweep engine:
 
@@ -19,8 +21,9 @@ Two backends, mirroring the sweep engine:
 * ``"jax"`` — every block matmul/einsum lowered to the Pallas
   ``com_matmul`` kernel (``repro.kernels.com_matmul``): the K-grid
   accumulates the C-block partial-sum chain in the f32 VMEM scratch —
-  exactly the COM partial-sum plane — and the ROFM-style epilogue (ReLU,
-  optional bias) fuses into the last K step before the single writeback.
+  exactly the COM partial-sum plane — and the ROFM-style epilogue (the
+  join's shortcut, then the ReLU) fuses into the last K step before the
+  single writeback.
   The whole layer chain jits into one executable; ``interpret=True``
   (automatic off-TPU) runs the same kernel path on CPU CI.
 
@@ -44,6 +47,7 @@ from repro.core.simulator import (
     Events,
     conv_block_events,
     fc_block_events,
+    pool_np,
     run_conv_block_chain,
     run_fc_block_chain,
 )
@@ -52,6 +56,8 @@ if TYPE_CHECKING:
     from repro.kernels.com_matmul import MatmulPlan
 
 BACKENDS: Tuple[str, ...] = ("numpy", "jax")
+#: a layer's ``activation`` → the kernel's epilogue activation
+ACTIVATIONS: Dict[str, Optional[str]] = {"relu": "relu", "none": None}
 
 
 def default_interpret() -> bool:
@@ -67,34 +73,67 @@ def _pooled_hw(layer: ConvSpec) -> Tuple[int, int]:
     """Feature-map height/width after the layer's fused pooling (if any)."""
     h, w = layer.h_out, layer.w_out
     if layer.pool_k > 0:
-        k, s = layer.pool_k, layer.pool_stride
-        h, w = (h - k) // s + 1, (w - k) // s + 1
+        k, s, p = layer.pool_k, layer.pool_stride, layer.pool_padding
+        h, w = (h + 2 * p - k) // s + 1, (w + 2 * p - k) // s + 1
+    if layer.avg_pool:
+        h, w = 1, 1
     return h, w
 
 
+def _input_from(layers, i: int) -> Optional[str]:
+    """The name of the layer whose output ``layers[i]`` reads; None for
+    the images."""
+    src = getattr(layers[i], "input_from", None)
+    if src is None and i > 0:
+        src = layers[i - 1].name
+    return src
+
+
+def _read_by_name(layers) -> Tuple[set, set]:
+    """``(kept, shortcut)``: the layers whose outputs a later layer reads
+    by name (``input_from`` or ``residual_from``), which the walk keeps,
+    and those read only as a join's shortcut: the projections."""
+    inputs = {_input_from(layers, i) for i in range(len(layers))}
+    named = {getattr(l, "input_from", None) for l in layers}
+    residuals = {getattr(l, "residual_from", None) for l in layers}
+    return (named | residuals) - {None}, residuals - inputs - {None}
+
+
 def _chain_shapes(layers) -> List[Tuple[int, ...]]:
-    """Validate that every layer's OFM feeds the next layer's IFM; return
+    """Validate that every layer reads a tensor an earlier layer produces
+    (the previous layer's output, or the one ``input_from`` names), that
+    each join's shortcut (``residual_from``) has the shape of the layer's
+    output before its pool, and that every activation is known; return
     the per-layer *input* shapes (without the batch axis)."""
     shapes: List[Tuple[int, ...]] = []
-    prev: Optional[Tuple[int, ...]] = None  # OFM shape after pooling/flatten
+    outs: Dict[str, Tuple[int, ...]] = {}   # OFM shape after pool/flatten
     problems: List[str] = []
     for i, l in enumerate(layers):
+        src = _input_from(layers, i)
+        prev = outs.get(src) if src is not None else None
+        if src is not None and src not in outs:
+            problems.append(f"layers[{i}] ({l.name!r}) reads {src!r}, "
+                            "which no earlier layer produces")
+        if l.activation not in ACTIVATIONS:
+            problems.append(f"layers[{i}] ({l.name!r}) has activation "
+                            f"{l.activation!r}; known: {list(ACTIVATIONS)}")
         if isinstance(l, ConvSpec):
-            if l.residual_from is not None:
-                raise NotImplementedError(
-                    f"layer {l.name!r} has residual_from={l.residual_from!r}: "
-                    "the whole-program executor chains straight-line "
-                    "conv/FC programs (VGG-class); residual joins are not "
-                    "executed functionally yet"
-                )
             want = (l.h_in, l.w_in, l.c_in)
             if prev is not None and prev != want:
                 problems.append(
-                    f"layers[{i}] ({l.name!r}) expects IFM {want}, but the "
-                    f"previous layer produces {prev}"
+                    f"layers[{i}] ({l.name!r}) expects IFM {want}, but "
+                    f"{src!r} produces {prev}"
+                )
+            res = l.residual_from
+            joined = (l.h_out, l.w_out, l.c_out)
+            if res is not None and outs.get(res) != joined:
+                problems.append(
+                    f"layers[{i}] ({l.name!r}) joins the output of {res!r}, "
+                    f"{outs.get(res, 'which no earlier layer produces')}, "
+                    f"to its own {joined}"
                 )
             shapes.append(want)
-            prev = _pooled_hw(l) + (l.c_out,)
+            outs[l.name] = _pooled_hw(l) + (l.c_out,)
         else:
             want = (l.c_in,)
             if prev is not None:
@@ -102,11 +141,11 @@ def _chain_shapes(layers) -> List[Tuple[int, ...]]:
                 if got != want:
                     problems.append(
                         f"layers[{i}] ({l.name!r}) expects {l.c_in} inputs, "
-                        f"but the previous layer produces {prev} "
+                        f"but {src!r} produces {prev} "
                         f"(flattens to {got[0]})"
                     )
             shapes.append(want)
-            prev = (l.c_out,)
+            outs[l.name] = (l.c_out,)
     if problems:
         raise ValueError(
             "workload is not an executable image→logits chain:\n"
@@ -141,19 +180,6 @@ def random_weights(program_or_workload, seed: int = 0) -> Dict[str, np.ndarray]:
     return out
 
 
-def _maxpool_np(x: np.ndarray, k: int, s: int) -> np.ndarray:
-    """Max pool (B, H, W, C) with window k, stride s — the functional twin
-    of the M-type CMP chain (``Func.CMP``) the schedule compiler emits."""
-    B, H, W, C = x.shape
-    Ho, Wo = (H - k) // s + 1, (W - k) // s + 1
-    out = None
-    for i in range(k):
-        for j in range(k):
-            v = x[:, i:i + (Ho - 1) * s + 1:s, j:j + (Wo - 1) * s + 1:s, :]
-            out = v if out is None else np.maximum(out, v)
-    return out
-
-
 def jax_forward(program, *, interpret: bool,
                 block_m: Optional[int] = None, block_n: Optional[int] = None,
                 block_k: Optional[int] = None, images: Optional[int] = None):
@@ -161,8 +187,12 @@ def jax_forward(program, *, interpret: bool,
 
     ``forward(x, ws)`` maps float32 images ``(B, *input_shape)`` and the
     float32 weights ``ws`` (one per layer, in layer order) to logits, with
-    one Pallas ``com_matmul`` call per conv/FC layer. ``block_*`` fix that
-    block dimension of every kernel; the others are chosen per layer from
+    one Pallas ``com_matmul`` call per conv/FC layer. The chain is a walk
+    over named tensors: a layer reads the previous layer's output or the
+    one its ``input_from`` names, and a join's shortcut (the output its
+    ``residual_from`` names) goes into the join layer's kernel, added to
+    the accumulator before the activation. ``block_*`` fix that block
+    dimension of every kernel; the others are chosen per layer from
     its matmul's shape (:meth:`ProgramExecutor.plan` lists them): the
     shape of the batch ``forward`` is called with, or of a batch of
     ``images`` where that is given. The sharded executor gives the whole
@@ -172,70 +202,104 @@ def jax_forward(program, *, interpret: bool,
     inputs (``jax.jit(forward).lower(x, ws).compile()``) gives the
     executable's HLO text and memory analysis for a described TPU.
     """
+    import contextlib
+
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.com_matmul import com_matmul_padded
 
     layer_programs = program.layer_programs
+    layers = program.workload.layers
+    kept, shortcut = _read_by_name(layers)
+    sources = [_input_from(layers, i) for i in range(len(layers))]
     given = dict(block_m=block_m, block_n=block_n, block_k=block_k)
     blocks = [given] * len(layer_programs)
     if images is not None:
         blocks = [dict(block_m=p.bm, block_n=p.bn, block_k=p.bk)
                   for _, p in matmul_plans(program, images, **given)]
 
-    def matmul(l, x2d, w2d, blk):
+    def matmul(l, x2d, w2d, blk, residual=None):
         # one COM kernel call per layer matmul: the K-grid walks the
         # C-block chain, partial sums riding the f32 VMEM scratch;
-        # the ReLU epilogue fuses into the last K step (M-type ACT).
-        # Blocks not given are chosen from the matmul's shape
-        # (``choose_blocks``), on a TPU and in interpret mode alike.
+        # the join and the activation fuse into the last K step (M-type
+        # Bp, then ACT). Blocks not given are chosen from the matmul's
+        # shape (``choose_blocks``), on a TPU and in interpret mode alike.
         # The kernel's name is the device op's name in a trace.
         return com_matmul_padded(
-            x2d, w2d, activation="relu", **blk,
+            x2d, w2d, activation=ACTIVATIONS[l.activation],
+            residual=residual, residual_first=residual is not None, **blk,
             interpret=interpret, name=f"com_matmul_{l.name}",
         )
 
     def forward(x, ws):
         # a named scope per layer and per step of it: they change the ops'
-        # metadata only, so a device trace's ops map back to the layer
-        for lp, w, blk in zip(layer_programs, ws, blocks):
+        # metadata only, so a device trace's ops map back to the layer;
+        # a projection's steps are in a ``shortcut`` scope besides
+        outs = {}
+        for lp, w, blk, src in zip(layer_programs, ws, blocks, sources):
             l = lp.layer
-            with jax.named_scope(l.name):
+            h = outs[src] if src in outs else x
+            with jax.named_scope(l.name), (
+                    jax.named_scope("shortcut") if l.name in shortcut
+                    else contextlib.nullcontext()):
                 if isinstance(l, ConvSpec):
-                    x = conv(l, x, w, blk)
+                    y = conv(l, h, w, blk, outs.get(l.residual_from))
                 else:
-                    if x.ndim > 2:
-                        x = x.reshape(x.shape[0], -1)
+                    if h.ndim > 2:
+                        h = h.reshape(h.shape[0], -1)
                     with jax.named_scope("matmul"):
-                        x = matmul(l, x, w, blk)
+                        y = matmul(l, h, w, blk)
+            if l.name in kept:
+                outs[l.name] = y
+            x = y
         return x
 
-    def conv(l, x, w, blk):
+    def conv(l, x, w, blk, residual):
         K, P, S = l.k, l.padding, l.stride
         Ho, Wo = l.h_out, l.w_out
         B = x.shape[0]
+        def tap(xp, kr, kc):
+            # the input under kernel tap (kr, kc) at every output pixel;
+            # a strided one is one lax.slice, where strided indexing
+            # would lower to a gather
+            if S == 1:
+                return xp[:, kr:kr + Ho, kc:kc + Wo, :]
+            return jax.lax.slice(
+                xp, (0, kr, kc, 0),
+                (B, kr + (Ho - 1) * S + 1, kc + (Wo - 1) * S + 1, l.c_in),
+                (1, S, S, 1))
+
         with jax.named_scope("im2col"):
-            xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
-            cols = [
-                xp[:, kr:kr + (Ho - 1) * S + 1:S,
-                   kc:kc + (Wo - 1) * S + 1:S, :]
-                for kr in range(K) for kc in range(K)
-            ]
-            # im2col in (kr, kc, c) order == w.reshape row-major
-            patches = jnp.concatenate(cols, axis=-1).reshape(
-                B * Ho * Wo, K * K * l.c_in)
+            if K == 1 and P == 0:
+                # a 1x1 convolution reads its input as it is, or one
+                # strided slice of it
+                patches = x if S == 1 else tap(x, 0, 0)
+            else:
+                xp = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
+                cols = [tap(xp, kr, kc)
+                        for kr in range(K) for kc in range(K)]
+                # im2col in (kr, kc, c) order == w.reshape row-major
+                patches = jnp.concatenate(cols, axis=-1)
+            patches = patches.reshape(B * Ho * Wo, K * K * l.c_in)
         with jax.named_scope("matmul"):
+            if residual is not None:
+                residual = residual.reshape(B * Ho * Wo, l.c_out)
             y = matmul(
-                l, patches, w.reshape(K * K * l.c_in, l.c_out), blk,
+                l, patches, w.reshape(K * K * l.c_in, l.c_out), blk, residual,
             ).reshape(B, Ho, Wo, l.c_out)
         if l.pool_k > 0:
+            p = l.pool_padding
             with jax.named_scope("pool"):
                 y = jax.lax.reduce_window(
                     y, -jnp.inf, jax.lax.max,
                     (1, l.pool_k, l.pool_k, 1),
-                    (1, l.pool_stride, l.pool_stride, 1), "VALID",
+                    (1, l.pool_stride, l.pool_stride, 1),
+                    ((0, 0), (p, p), (p, p), (0, 0)) if p else "VALID",
                 )
+        if l.avg_pool:
+            with jax.named_scope("pool"):
+                y = jnp.mean(y, axis=(1, 2), keepdims=True)
         return y
 
     return forward
@@ -246,12 +310,14 @@ def matmul_plans(program, images: int, *, block_m: Optional[int] = None,
                  ) -> List[Tuple[str, "MatmulPlan"]]:
     """``(layer name, MatmulPlan)`` for each layer's ``com_matmul_padded``
     call in :func:`jax_forward`'s chain on a batch of ``images``: the
-    matmul's ``(M, K, N)``, its blocks, grid steps and padded bytes."""
+    matmul's ``(M, K, N)``, its blocks, grid steps and padded bytes, and
+    whether it joins a shortcut and that shortcut's bytes."""
     from repro.kernels.com_matmul import matmul_plan
 
-    return [(lp.layer.name, matmul_plan(*_matmul_dims(lp.layer, images),
-                                        _ITEMSIZE, block_m=block_m,
-                                        block_n=block_n, block_k=block_k))
+    return [(lp.layer.name, matmul_plan(
+                *_matmul_dims(lp.layer, images), _ITEMSIZE, block_m=block_m,
+                block_n=block_n, block_k=block_k,
+                residual=getattr(lp.layer, "residual_from", None) is not None))
             for lp in program.layer_programs]
 
 
@@ -421,16 +487,25 @@ class ProgramExecutor:
 
     # ---- numpy backend: the shared block-semantics oracle ----
     def _run_numpy(self, x: np.ndarray) -> np.ndarray:
-        for lp, w in zip(self.program.layer_programs, self.weights):
+        # the same walk over named tensors as jax_forward's; a join's
+        # shortcut is added in its last C-block's epilogue
+        layers = self.program.workload.layers
+        kept, _ = _read_by_name(layers)
+        outs: Dict[str, np.ndarray] = {}
+        for i, (lp, w) in enumerate(zip(self.program.layer_programs,
+                                        self.weights)):
             l = lp.layer
+            h = outs.get(_input_from(layers, i), x)
             if isinstance(l, ConvSpec):
-                x = run_conv_block_chain(lp, w, x)
-                if l.pool_k > 0:
-                    x = _maxpool_np(x, l.pool_k, l.pool_stride)
+                res = outs[l.residual_from] if l.residual_from else None
+                y = pool_np(l, run_conv_block_chain(lp, w, h, res))
             else:
-                if x.ndim > 2:
-                    x = x.reshape(x.shape[0], -1)  # conv→flatten→FC
-                x = run_fc_block_chain(lp, w, x)
+                if h.ndim > 2:
+                    h = h.reshape(h.shape[0], -1)  # conv→flatten→FC
+                y = run_fc_block_chain(lp, w, h)
+            if l.name in kept:
+                outs[l.name] = y
+            x = y
         return x
 
     # ---- jax backend: block chains lowered to the Pallas COM kernel ----
@@ -498,7 +573,8 @@ class ProgramExecutor:
             return whole
         rows = -(-batch // self.n_shards)   # images on each device
         return [(name, matmul_plan(*_matmul_dims(lp.layer, rows), _ITEMSIZE,
-                                   block_m=p.bm, block_n=p.bn, block_k=p.bk))
+                                   block_m=p.bm, block_n=p.bn, block_k=p.bk,
+                                   residual=p.joins))
                 for (name, p), lp in zip(whole, self.program.layer_programs)]
 
     def _jax_args(self, x: np.ndarray):
@@ -513,6 +589,9 @@ class ProgramExecutor:
                 sp.count("grid_steps", sum(p.steps for p in plan))
                 sp.count("pad_bytes_per_call",
                          sum(p.pad_bytes for p in plan))
+                sp.count("residual_bytes_per_call",
+                         sum(p.residual_bytes for p in plan))
+                sp.count("joins", sum(p.joins for p in plan))
         chain, ws, place = self._jax_chain
         jit_forward = chain(x.shape[0])
         with spans.span("executor.upload") as sp:
@@ -537,8 +616,9 @@ class ProgramExecutor:
         ``executor.run`` over ``executor.batch`` (the float64 conversion),
         then, on the jax backend, ``executor.build`` (first call only;
         besides ``bytes_weights`` it counts, from :meth:`plan` for that
-        call's batch, the kernels' ``grid_steps`` on each device and the
-        ``pad_bytes_per_call`` of their padded operands),
+        call's batch, the kernels' ``grid_steps`` on each device, the
+        ``pad_bytes_per_call`` of their padded operands, the ``joins``
+        and the ``residual_bytes_per_call`` of the shortcuts they read),
         ``executor.upload``, ``executor.dispatch`` (until the jitted chain
         returns) and ``executor.fetch`` (the logits to the host); the
         numpy backend's chain is ``executor.dispatch`` alone."""

@@ -41,9 +41,17 @@ class ConvSpec:
     w_in: int        # width
     stride: int = 1
     padding: int = 1
-    pool_k: int = 0   # pooling after this layer (K_p); 0 = none
+    pool_k: int = 0   # max pooling after this layer (K_p); 0 = none
     pool_stride: int = 2
-    residual_from: Optional[str] = None  # ResNet skip source
+    # the residual join: this layer's output is act(conv(x) + the output
+    # of layer ``residual_from``), the shortcut added before the activation
+    residual_from: Optional[str] = None
+    input_from: Optional[str] = None  # the layer it reads; None = previous
+    activation: str = "relu"          # "relu" or "none"
+    pool_padding: int = 0             # of the max pool, -inf at the border
+    # a global average pool over the output map after this layer (the
+    # M-type MUL, Tab. II); the event model does not price it
+    avg_pool: bool = False
 
     @property
     def h_out(self) -> int:
@@ -67,6 +75,7 @@ class FCSpec:
     name: str
     c_in: int
     c_out: int
+    activation: str = "relu"          # "relu" or "none"
 
     @property
     def macs(self) -> int:
@@ -269,8 +278,77 @@ def resnet18_cifar() -> "Workload":  # noqa: F821
                          residual_from=f"rn.c{co}b{b}a")  # skip via RIFM shortcut
             )
             c = co
+    # the global average pool takes the last 4x4 map to the FC's 512 inputs
+    layers[-1] = ConvSpec(**{**layers[-1].__dict__, "avg_pool": True})
     layers.append(FCSpec("rn.fc", 512, 10))
     return _workload("resnet18-cifar", layers)
+
+
+#: ResNet-50's stages: (bottleneck width, blocks, stride of the first block)
+RESNET50_STAGES = ((64, 3, 1), (128, 4, 2), (256, 6, 2), (512, 3, 2))
+RESNET50_EXPANSION = 4
+
+
+def bottleneck_resnet(name: str, *, image: int = 224, stem: int = 64,
+                      stages=RESNET50_STAGES,
+                      expansion: int = RESNET50_EXPANSION,
+                      classes: int = 1000) -> List:
+    """The layers of a ResNet v1.5 of bottleneck blocks on ``image`` x
+    ``image`` x 3 input, each named ``<name>.<layer>``:
+    :func:`resnet50_imagenet` at its published sizes, smaller ones for
+    tests.
+
+    A 7x7 stride-2 stem to ``stem`` channels and a 3x3 stride-2 max pool
+    with padding 1; then, for each ``(width, blocks, stride)`` of
+    ``stages``, that many bottleneck blocks ``1x1 -> 3x3 -> 1x1`` to
+    ``width * expansion`` channels, each joining as ``relu(conv(x) +
+    shortcut)``. The first block of a stage has a 1x1 projection shortcut
+    (``.proj``, no activation); it and the block's 3x3 convolution carry
+    the stage's stride (v1.5). A global average pool and an FC to
+    ``classes`` logits with no activation end it. Batch norm is folded
+    into the weights with a zero shift, so no layer has a bias. Layers
+    are in topological order, ``a``, ``b``, ``proj``, ``c`` in a block.
+    """
+    h = (image - 1) // 2 + 1                       # the stem's output
+    layers: List = [ConvSpec(f"{name}.stem", 7, 3, stem, image, image,
+                             stride=2, padding=3, pool_k=3, pool_stride=2,
+                             pool_padding=1)]
+    x, c, h = f"{name}.stem", stem, (h - 1) // 2 + 1   # the block input
+    for stage, (width, blocks, stride0) in enumerate(stages, 1):
+        out = width * expansion
+        for b in range(blocks):
+            s = stride0 if b == 0 else 1
+            block = f"{name}.s{stage}b{b}"
+            ho = (h - 1) // s + 1
+            layers += [
+                ConvSpec(f"{block}.a", 1, c, width, h, h, padding=0,
+                         input_from=x),
+                ConvSpec(f"{block}.b", 3, width, width, h, h, stride=s),
+            ]
+            shortcut = x
+            if b == 0:
+                shortcut = f"{block}.proj"
+                layers.append(ConvSpec(shortcut, 1, c, out, h, h, stride=s,
+                                       padding=0, input_from=x,
+                                       activation="none"))
+            layers.append(ConvSpec(f"{block}.c", 1, width, out, ho, ho,
+                                   padding=0, input_from=f"{block}.b",
+                                   residual_from=shortcut))
+            x, c, h = f"{block}.c", out, ho
+    layers[-1] = ConvSpec(**{**layers[-1].__dict__, "avg_pool": True})
+    layers.append(FCSpec(f"{name}.fc", c, classes, activation="none"))
+    return layers
+
+
+def resnet50_imagenet() -> "Workload":  # noqa: F821
+    """ResNet-50 v1.5 on 224x224x3 ImageNet input (He et al., arXiv
+    1512.03385, Table 1, the 50-layer column; v1.5 puts each bottleneck's
+    stride on its 3x3 convolution, as torchvision's ``resnet50`` does):
+    :func:`bottleneck_resnet` at its published sizes, 3, 4, 6 and 3
+    blocks of widths 64, 128, 256 and 512. 53 convolutions and 1 FC,
+    4.089 GMAC and 25.50 M weights an image. Departures: batch norm folded
+    into the weights with a zero shift (no biases)."""
+    return _workload("resnet50-imagenet", bottleneck_resnet("rn50"))
 
 
 NETWORKS = {
@@ -278,4 +356,10 @@ NETWORKS = {
     "vgg16-imagenet": vgg16_imagenet,
     "vgg19-imagenet": vgg19_imagenet,
     "resnet18-cifar": resnet18_cifar,
+}
+
+#: networks the executor runs that the sweep grids do not price:
+#: ``resolve_network`` finds them, ``available_networks`` does not list them
+EXECUTOR_NETWORKS = {
+    "resnet50-imagenet": resnet50_imagenet,
 }

@@ -92,16 +92,23 @@ def compile_conv_tile(layer: ConvSpec, kpos: int, is_last_row: bool) -> TileSche
 
 
 def compile_last_row_mtype(layer: ConvSpec) -> TileSchedule:
-    """M-type stream for the last-row tile: activation (+ pooling)."""
-    instrs: List = [MInstr(rx=Dir.PE, func=Func.ACT, tx=Dir.S)]
+    """M-type stream for the last-row tile: the residual join (Bp, the
+    shortcut added to the closed partial sum), the activation (a plain
+    pass where the layer has none), then the pooling: the max pool's CMP
+    chain, or the global average pool's MUL."""
+    instrs: List = []
+    if layer.residual_from is not None:
+        instrs.append(MInstr(rx=Dir.W, func=Func.BP, tx=Dir.S))  # skip path
+    act = Func.ACT if layer.activation == "relu" else Func.NONE
+    instrs.append(MInstr(rx=Dir.PE, func=act, tx=Dir.S))
     if layer.pool_k:
         p = pool_period(layer)
         # Cmp chain across the pooling window; emit result every p cycles
         for i in range(p - 1):
             instrs.append(MInstr(rx=Dir.W, func=Func.CMP, tx=Dir.NONE))
         instrs.append(MInstr(rx=Dir.W, func=Func.CMP, tx=Dir.S))
-    if layer.residual_from is not None:
-        instrs.append(MInstr(rx=Dir.W, func=Func.BP, tx=Dir.S))  # skip path
+    if layer.avg_pool:
+        instrs.append(MInstr(rx=Dir.W, func=Func.MUL, tx=Dir.S))
     table = ScheduleTable(instrs, period=max(len(instrs), 1))
     return TileSchedule(role="conv_last", table=table, active_frac=1.0)
 

@@ -73,13 +73,16 @@ LINK_PJ_PER_BIT = DEFAULT_ARCH.energy.link_pj_per_bit  # NoC pJ per bit-hop
 _CONV_CHUNK_BYTES = 32e6
 
 
-def run_conv_block_chain(lp, w: np.ndarray, x: np.ndarray) -> np.ndarray:
+def run_conv_block_chain(lp, w: np.ndarray, x: np.ndarray,
+                         residual: Optional[np.ndarray] = None) -> np.ndarray:
     """Execute one conv layer's compiled block chain, batched over a leading
     image axis: ``(B, H, W, C) -> (B, H_out, W_out, M)`` float64.
 
     This is THE block-chain semantics — partial sums accumulate across
     chained C-blocks, outputs concatenate across M-blocks, the last C-block
-    activates — shared by ``COMGridSim`` (B=1 cycle-level cross-validation)
+    joins the ``residual`` ``(B, H_out, W_out, M)`` where it is given (the
+    M-type Bp) and activates as the layer says — shared by ``COMGridSim``
+    (B=1 cycle-level cross-validation)
     and ``repro.core.executor.ProgramExecutor`` (whole-program batched
     runs). Each block evaluates as one full-image einsum vectorized over
     the ``oy`` axis; the gather is chunked over ``oy`` to bound the MAC
@@ -115,9 +118,40 @@ def run_conv_block_chain(lp, w: np.ndarray, x: np.ndarray) -> np.ndarray:
                     patches[..., cs:ce], w[:, :, cs:ce, ms:me],
                 )
                 acc = part if acc is None else acc + part
-            # chain closed: the last C-block's M-type tile activates
-            out[:, y0:y0 + chunk, :, ms:me] = np.maximum(acc, 0.0)
+            # chain closed: the last C-block's M-type tile joins the
+            # shortcut, then activates
+            if residual is not None:
+                acc = acc + residual[:, y0:y0 + chunk, :, ms:me]
+            out[:, y0:y0 + chunk, :, ms:me] = _activate(L, acc)
     return out
+
+
+def _activate(layer, acc: np.ndarray) -> np.ndarray:
+    return np.maximum(acc, 0.0) if layer.activation == "relu" else acc
+
+
+def pool_np(layer: ConvSpec, x: np.ndarray) -> np.ndarray:
+    """The pooling after ``layer`` on ``(B, H, W, C)``: the max pool (the
+    M-type CMP chain; window ``pool_k``, stride ``pool_stride``, ``-inf``
+    beyond a border of ``pool_padding``), then the global average pool
+    (MUL) to ``(B, 1, 1, C)``. Neither is part of ``COMGridSim``'s
+    output, which is the activation before the pool."""
+    if layer.pool_k > 0:
+        k, s, p = layer.pool_k, layer.pool_stride, layer.pool_padding
+        if p:
+            x = np.pad(x, ((0, 0), (p, p), (p, p), (0, 0)),
+                       constant_values=-np.inf)
+        B, H, W, C = x.shape
+        Ho, Wo = (H - k) // s + 1, (W - k) // s + 1
+        out = None
+        for i in range(k):
+            for j in range(k):
+                v = x[:, i:i + (Ho - 1) * s + 1:s, j:j + (Wo - 1) * s + 1:s, :]
+                out = v if out is None else np.maximum(out, v)
+        x = out
+    if layer.avg_pool:
+        x = x.mean(axis=(1, 2), keepdims=True)
+    return x
 
 
 def conv_block_events(lp, arch: ArchSpec) -> Events:
@@ -178,8 +212,9 @@ def run_fc_block_chain(lp, w: np.ndarray, x: np.ndarray) -> np.ndarray:
 
     Each M-block is a column of chained C-block rows, each row adding its
     MVM slice to the arriving sum (ADD_RX | ADD_PE) and forwarding S; the
-    last row activates (M-type ACT). Shared by ``COMGridSim`` and
-    ``ProgramExecutor`` — see :func:`run_conv_block_chain`.
+    last row activates (M-type ACT) where the layer has an activation.
+    Shared by ``COMGridSim`` and ``ProgramExecutor`` — see
+    :func:`run_conv_block_chain`.
     """
     L = lp.layer
     x = x.astype(np.float64)
@@ -192,7 +227,7 @@ def run_fc_block_chain(lp, w: np.ndarray, x: np.ndarray) -> np.ndarray:
             part = x[:, cs:ce] @ w[cs:ce, ms:me]
             acc = part if acc is None else acc + part
         (ms, me) = lp.block(0, mi).m_range
-        out[:, ms:me] = np.maximum(acc, 0.0)
+        out[:, ms:me] = _activate(L, acc)
     return out
 
 

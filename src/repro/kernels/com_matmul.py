@@ -5,7 +5,10 @@ adapted to the MXU: the K-loop accumulates partial sums in a VMEM f32
 scratch (the analogue of partial sums riding the ROFM plane — never spilled
 to HBM), and the epilogue (Add=bias, Act=relu/silu/gelu, Bp=residual) is
 applied on the LAST K step before the single HBM writeback — computing on
-the move instead of a separate elementwise pass over HBM.
+the move instead of a separate elementwise pass over HBM. The residual is
+added after the activation (``act(acc + bias) + residual``), or, with
+``residual_first``, before it: ``act(acc + bias + residual)``, a residual
+network's join.
 
 :func:`com_matmul` takes its blocks as given (128 by default).
 :func:`com_matmul_padded` takes each block it is not given from
@@ -27,9 +30,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _epilogue(acc, bias, activation, residual):
+def _epilogue(acc, bias, activation, residual, residual_first=False):
     if bias is not None:
         acc = acc + bias.astype(jnp.float32)
+    if residual is not None and residual_first:
+        acc = acc + residual.astype(jnp.float32)
+        residual = None
     if activation == "relu":
         acc = jax.nn.relu(acc)
     elif activation == "silu":
@@ -41,7 +47,8 @@ def _epilogue(acc, bias, activation, residual):
     return acc
 
 
-def _kernel(x_ref, w_ref, *rest, activation, nk, has_bias, has_residual):
+def _kernel(x_ref, w_ref, *rest, activation, nk, has_bias, has_residual,
+            residual_first):
     # rest = [bias_ref?, residual_ref?, o_ref, acc_ref]
     idx = 0
     bias_ref = rest[idx] if has_bias else None
@@ -75,6 +82,7 @@ def _kernel(x_ref, w_ref, *rest, activation, nk, has_bias, has_residual):
             bias_ref[...] if bias_ref is not None else None,
             activation,
             res_ref[...] if res_ref is not None else None,
+            residual_first,
         )
         o_ref[...] = acc.astype(o_ref.dtype)
 
@@ -86,14 +94,17 @@ def com_matmul(
     bias: Optional[jnp.ndarray] = None,
     activation: Optional[str] = None,
     residual: Optional[jnp.ndarray] = None,
+    residual_first: bool = False,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
     interpret: bool = False,
     name: Optional[str] = None,
 ) -> jnp.ndarray:
-    """x: (M, K), w: (K, N) -> (M, N) with fused epilogue. ``name`` names
-    the kernel, and so its op in the compiled HLO and a device trace."""
+    """x: (M, K), w: (K, N) -> (M, N) with fused epilogue; ``residual``
+    (M, N) is added after the activation, or before it where
+    ``residual_first``. ``name`` names the kernel, and so its op in the
+    compiled HLO and a device trace."""
     M, K = x.shape
     K2, N = w.shape
     assert K == K2
@@ -119,6 +130,7 @@ def com_matmul(
     kernel = functools.partial(
         _kernel, activation=activation, nk=nk,
         has_bias=bias is not None, has_residual=residual is not None,
+        residual_first=residual_first,
     )
     return pl.pallas_call(
         kernel,
@@ -148,10 +160,12 @@ def _sublanes(itemsize: int) -> int:
     return 8 * max(1, 4 // itemsize)
 
 
-def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int) -> int:
+def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int,
+               residual: bool = False) -> int:
     """Scoped VMEM of a :func:`com_matmul` on blocks ``(bm, bk) @ (bk,
     bn)``, each rounded up to whole tiles as Mosaic lays them out: x, w and
-    the output double-buffered, the float32 accumulator, and the scratch
+    the output double-buffered, with a ``residual`` its ``(bm, bn)`` block
+    double-buffered too, the float32 accumulator, and the scratch
     Mosaic adds for the product. That scratch was read off the v5e
     compiler (the least ``vmem_limit_bytes`` that compiles, float32
     operands at ``HIGHEST``): up to four float32 ``(bm, 128)`` stripes,
@@ -164,8 +178,9 @@ def vmem_bytes(bm: int, bk: int, bn: int, itemsize: int) -> int:
         return _round_up(rows, rows_tile) * _round_up(cols, LANES) * size
 
     x = tiles(bm, bk, itemsize, sub)
-    buffers = 2 * (x + tiles(bk, bn, itemsize, sub)
-                   + tiles(bm, bn, itemsize, sub)) + tiles(bm, bn, 4, 8)
+    out = tiles(bm, bn, itemsize, sub)
+    buffers = 2 * (x + tiles(bk, bn, itemsize, sub) + out
+                   + (out if residual else 0)) + tiles(bm, bn, 4, 8)
     scratch = 4 * tiles(bm, LANES, 4, 8)
     if _round_up(bn, LANES) > LANES:
         scratch += 4 * x
@@ -195,10 +210,12 @@ def _block_candidates(dim: int, tile: int, given: Optional[int]) -> List[int]:
 def choose_blocks(M: int, K: int, N: int, itemsize: int, *,
                   block_m: Optional[int] = None,
                   block_n: Optional[int] = None,
-                  block_k: Optional[int] = None) -> Tuple[int, int, int]:
+                  block_k: Optional[int] = None,
+                  residual: bool = False) -> Tuple[int, int, int]:
     """``(bm, bn, bk)`` for an ``(M, K) @ (K, N)`` :func:`com_matmul` of
-    ``itemsize``-byte operands; a block given is kept, and the others are
-    chosen around it.
+    ``itemsize``-byte operands, with an ``(M, N)`` residual where
+    ``residual``; a block given is kept, and the others are chosen around
+    it.
 
     Each block is the whole dimension or a multiple of its tile (rows of
     x and the output: 8 for 4-byte types; K and N: 128 lanes). Among
@@ -217,7 +234,7 @@ def choose_blocks(M: int, K: int, N: int, itemsize: int, *,
                               _round_up(K, bk))
                 steps = (Mp // bm) * (Np // bn) * (Kp // bk)
                 reads = Mp * Kp * (Np // bn) + Kp * Np * (Mp // bm)
-                key = (max(0, vmem_bytes(bm, bk, bn, itemsize)
+                key = (max(0, vmem_bytes(bm, bk, bn, itemsize, residual)
                            - VMEM_BUDGET_BYTES),
                        (Mp, Np, Kp) != (M, N, K), steps, reads, -bm)
                 if best_key is None or key < best_key:
@@ -227,7 +244,8 @@ def choose_blocks(M: int, K: int, N: int, itemsize: int, *,
 
 class MatmulPlan(NamedTuple):
     """One :func:`com_matmul_padded` call as it runs: its shape, blocks,
-    grid steps, and the bytes of the padded copies it makes."""
+    grid steps, the bytes of the padded copies it makes, and, where it
+    joins a residual, the bytes of that ``(M, N)`` shortcut it reads."""
 
     m: int
     k: int
@@ -237,20 +255,27 @@ class MatmulPlan(NamedTuple):
     bk: int
     steps: int
     pad_bytes: int
+    residual_bytes: int = 0
+    joins: bool = False
 
 
 def matmul_plan(M: int, K: int, N: int, itemsize: int, *,
                 block_m: Optional[int] = None, block_n: Optional[int] = None,
-                block_k: Optional[int] = None) -> MatmulPlan:
-    """How :func:`com_matmul_padded` runs ``(M, K) @ (K, N)`` with the
-    blocks it is given and :func:`choose_blocks` picks the rest."""
+                block_k: Optional[int] = None,
+                residual: bool = False) -> MatmulPlan:
+    """How :func:`com_matmul_padded` runs ``(M, K) @ (K, N)``, joining an
+    ``(M, N)`` residual where ``residual``, with the blocks it is given
+    and :func:`choose_blocks` picks the rest."""
     bm, bn, bk = choose_blocks(M, K, N, itemsize, block_m=block_m,
-                               block_n=block_n, block_k=block_k)
+                               block_n=block_n, block_k=block_k,
+                               residual=residual)
     Mp, Kp, Np = _round_up(M, bm), _round_up(K, bk), _round_up(N, bn)
     pad = ((Mp * Kp if (Mp, Kp) != (M, K) else 0)
-           + (Kp * Np if (Kp, Np) != (K, N) else 0))
+           + (Kp * Np if (Kp, Np) != (K, N) else 0)
+           + (Mp * Np if residual and (Mp, Np) != (M, N) else 0))
     return MatmulPlan(M, K, N, bm, bn, bk,
-                      (Mp // bm) * (Np // bn) * (Kp // bk), pad * itemsize)
+                      (Mp // bm) * (Np // bn) * (Kp // bk), pad * itemsize,
+                      M * N * itemsize if residual else 0, residual)
 
 
 def com_matmul_padded(
@@ -259,6 +284,8 @@ def com_matmul_padded(
     *,
     bias: Optional[jnp.ndarray] = None,
     activation: Optional[str] = None,
+    residual: Optional[jnp.ndarray] = None,
+    residual_first: bool = False,
     block_m: Optional[int] = None,
     block_n: Optional[int] = None,
     block_k: Optional[int] = None,
@@ -281,7 +308,8 @@ def com_matmul_padded(
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     plan = matmul_plan(M, K, N, x.dtype.itemsize, block_m=block_m,
-                       block_n=block_n, block_k=block_k)
+                       block_n=block_n, block_k=block_k,
+                       residual=residual is not None)
     Mp, Kp, Np = (_round_up(M, plan.bm), _round_up(K, plan.bk),
                   _round_up(N, plan.bn))
     # the block padding and the slice back are scoped apart from the
@@ -293,8 +321,14 @@ def com_matmul_padded(
         if bias is not None:
             assert bias.shape == (N,)
             bp = jnp.pad(bias, (0, Np - N)) if Np != N else bias
+        rp = None
+        if residual is not None:
+            assert residual.shape == (M, N), (residual.shape, (M, N))
+            rp = (jnp.pad(residual, ((0, Mp - M), (0, Np - N)))
+                  if (Mp, Np) != (M, N) else residual)
     out = com_matmul(
-        xp, wp, bias=bp, activation=activation,
+        xp, wp, bias=bp, activation=activation, residual=rp,
+        residual_first=residual_first,
         block_m=plan.bm, block_n=plan.bn, block_k=plan.bk,
         interpret=interpret, name=name,
     )

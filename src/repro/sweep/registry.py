@@ -2,7 +2,9 @@
 
 Two namespaces:
   * the paper's Tab. IV CNNs (``vgg11-cifar`` ... ``resnet18-cifar``) from
-    ``repro.core.mapping.NETWORKS``;
+    ``repro.core.mapping.NETWORKS``, and the networks only the executor
+    runs (``resnet50-imagenet``) from ``EXECUTOR_NETWORKS``, which no
+    sweep grid lists;
   * ``llm:<arch-id>`` for every seed config in ``repro.configs`` via the
     FC-chain bridge (``repro.sweep.llm_bridge``).
 
@@ -16,7 +18,7 @@ from functools import lru_cache
 from typing import Tuple
 
 from repro.configs import ARCHS, get_config
-from repro.core.mapping import NETWORKS
+from repro.core.mapping import EXECUTOR_NETWORKS, NETWORKS
 from repro.core.program import Workload
 from repro.sweep.llm_bridge import fc_network_from_config
 
@@ -37,6 +39,8 @@ def resolve_network(name: str) -> Workload:
     share one workload object and one compile cache line."""
     if name in NETWORKS:
         return NETWORKS[name]()
+    if name in EXECUTOR_NETWORKS:
+        return EXECUTOR_NETWORKS[name]()
     if name.startswith(LLM_PREFIX):
         return Workload(
             name, fc_network_from_config(get_config(name[len(LLM_PREFIX):])))

@@ -16,10 +16,11 @@ from repro.kernels.com_matmul import (
 )
 from repro.sweep.registry import resolve_network
 
-# (network, images on one device): the three cells, and one shard of
+# (network, images on one device): the cells, and one shard of
 # vgg16-imagenet's batch of 8 over four devices (chip_smoke --four-chips)
 CHAINS = [("vgg16-imagenet", 1), ("vgg16-imagenet", 32),
-          ("vgg11-cifar", 256), ("vgg16-imagenet", 2)]
+          ("vgg11-cifar", 256), ("vgg16-imagenet", 2),
+          ("resnet50-imagenet", 32), ("vgg11-cifar", 1)]
 
 
 def _steps_128(p):
@@ -51,7 +52,8 @@ def test_chosen_blocks_are_legal_dividing_and_fit(network, images, name,
     assert (p.m % p.bm, p.k % p.bk, p.n % p.bn) == (0, 0, 0), p
     assert p.pad_bytes == 0
     assert p.steps == (p.m // p.bm) * (p.k // p.bk) * (p.n // p.bn)
-    assert vmem_bytes(p.bm, p.bk, p.bn, 4) <= VMEM_BUDGET_BYTES
+    # a join's double-buffered residual block counts too
+    assert vmem_bytes(p.bm, p.bk, p.bn, 4, p.joins) <= VMEM_BUDGET_BYTES
     assert p.steps <= _steps_128(p)
 
 
@@ -90,3 +92,53 @@ def test_a_block_given_is_kept_and_the_others_are_chosen():
     assert (p.bm, p.bn, p.bk) == (48, 128, 128)
     assert p.steps == 2 * 1 * 5
     assert p.pad_bytes == 4 * (96 * 640 + 640 * 128)
+
+
+# (bm, bn, bk) of each layer, as chosen before residual joins existed;
+# the joins' VMEM term leaves every chain without a join as it was
+VGG_BLOCKS = {
+    ("vgg16-imagenet", 32): [
+        (2048, 64, 27), (1024, 64, 576), (1024, 128, 576), (784, 128, 1152),
+        (784, 128, 1152), (448, 128, 2304), (448, 128, 2304),
+        (448, 128, 2304), (512, 512, 512), (512, 512, 512), (448, 128, 2304),
+        (448, 128, 2304), (448, 128, 2304), (32, 4096, 256), (32, 4096, 256),
+        (32, 1000, 1024)],
+    ("vgg16-imagenet", 1): [
+        (1792, 64, 27), (1024, 64, 576), (896, 128, 576), (784, 128, 1152),
+        (784, 128, 1152), (448, 128, 2304), (448, 128, 2304),
+        (784, 128, 1152), (784, 128, 1152), (784, 128, 1152),
+        (196, 512, 1152), (196, 512, 1152), (196, 512, 1152),
+        (1, 4096, 256), (1, 4096, 256), (1, 1000, 1024)],
+    ("vgg11-cifar", 256): [
+        (2048, 64, 27), (1024, 128, 576), (256, 256, 1152), (1024, 128, 768),
+        (512, 512, 384), (512, 512, 512), (512, 512, 512), (512, 512, 512),
+        (256, 2048, 256), (256, 2048, 256), (256, 10, 2048)],
+}
+
+
+@pytest.mark.parametrize("network, images", sorted(VGG_BLOCKS))
+def test_vgg_plans_are_unchanged(network, images):
+    program = compile_program(resolve_network(network))
+    from repro.core.executor import ProgramExecutor, random_weights
+
+    plan = ProgramExecutor(program, random_weights(program),
+                           backend="jax").plan(images)
+    assert [(p.bm, p.bn, p.bk) for _, p in plan] == \
+        VGG_BLOCKS[network, images]
+    assert not any(p.joins or p.residual_bytes or p.pad_bytes
+                   for _, p in plan)
+
+
+def test_a_join_counts_its_residual_block_in_vmem():
+    """The residual's ``(bm, bn)`` block, double-buffered, is one more
+    output block twice over."""
+    plain = vmem_bytes(1024, 64, 256, 4)
+    assert vmem_bytes(1024, 64, 256, 4, True) - plain == 2 * 1024 * 256 * 4
+    # ResNet-50's stage-2 join: its fastest blocks without a residual
+    # (896 rows) would not fit with one, so the chooser takes 784
+    m, k, n = 25_088, 128, 512
+    bm, bn, bk = choose_blocks(m, k, n, 4)
+    rm, rn, rk = choose_blocks(m, k, n, 4, residual=True)
+    assert vmem_bytes(rm, rk, rn, 4, True) <= VMEM_BUDGET_BYTES
+    assert vmem_bytes(bm, bk, bn, 4, True) > VMEM_BUDGET_BYTES
+    assert (bm, rm) == (896, 784)

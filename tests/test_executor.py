@@ -14,7 +14,6 @@ import pytest
 from repro.core import cache_stats, compile_program
 from repro.core.executor import (
     ProgramExecutor,
-    _maxpool_np,
     random_weights,
 )
 from repro.core.mapping import ConvSpec, FCSpec, resnet18_cifar, vgg11_cifar
@@ -24,6 +23,7 @@ from repro.core.simulator import (
     DominoModel,
     EVENT_FIELDS,
     network_event_totals,
+    pool_np,
     reference_conv,
     reference_fc,
 )
@@ -37,7 +37,7 @@ def reference_forward(layers, weights, images):
         if isinstance(l, ConvSpec):
             y = np.stack([reference_conv(xi, weights[l.name], l) for xi in x])
             if l.pool_k > 0:
-                y = _maxpool_np(y, l.pool_k, l.pool_stride)
+                y = pool_np(l, y)
             x = y
         else:
             if x.ndim > 2:
@@ -201,9 +201,20 @@ def test_non_chaining_workload_rejected():
 
 
 def test_residual_workloads_are_rejected_for_now():
+    """A join runs only where its shortcut exists and has the shape of the
+    join's output: one naming no earlier layer, or a tensor of another
+    shape, is rejected; resnet18-cifar's joins are sound."""
+    layers = list(resnet18_cifar().layers)
     program = compile_program(resnet18_cifar())
-    with pytest.raises(NotImplementedError, match="residual"):
-        program.executor(random_weights(program))
+    program.executor(random_weights(program))
+    i = next(i for i, l in enumerate(layers)
+             if l.residual_from and l.c_out == 128)   # a 16x16x128 join
+    for bad in ("rn.nowhere", layers[0].name):   # unknown; 32x32x64
+        wl = Workload("broken-join", layers[:i] + [
+            ConvSpec(**{**layers[i].__dict__, "residual_from": bad})]
+            + layers[i + 1:])
+        with pytest.raises(ValueError, match="joins the output"):
+            compile_program(wl).executor(random_weights(wl))
 
 
 def test_cache_stats_reports_bounded_caches():
@@ -255,7 +266,8 @@ def test_run_emits_its_span_tree_each_call(vgg11_setup):
     assert build == {"bytes_weights": 4 * sum(w.size
                                               for w in weights.values()),
                      "grid_steps": sum(p.steps for p in plan),
-                     "pad_bytes_per_call": sum(p.pad_bytes for p in plan)}
+                     "pad_bytes_per_call": sum(p.pad_bytes for p in plan),
+                     "residual_bytes_per_call": 0, "joins": 0}
     np.testing.assert_array_equal(first.outputs, again.outputs)
 
 

@@ -112,6 +112,53 @@ def test_com_matmul_residual_epilogue():
     )
 
 
+
+def _operands(m=128, k=128, n=128):
+    key = jax.random.PRNGKey(3)
+    return [jax.random.normal(jax.random.fold_in(key, i), shape)
+            for i, shape in enumerate(((m, k), (k, n), (m, n)))]
+
+
+def test_com_matmul_residual_order_is_unchanged_by_default():
+    """The existing callers' order, ``act(acc) + residual``, to the bit:
+    the default, ``residual_first=False``, and the same sum made outside
+    the kernel all agree."""
+    x, w, r = _operands()
+    y = com_matmul(x, w, residual=r, activation="relu", interpret=True)
+    explicit = com_matmul(x, w, residual=r, activation="relu",
+                          residual_first=False, interpret=True)
+    outside = com_matmul(x, w, activation="relu", interpret=True) + r
+    np.testing.assert_array_equal(y, explicit)
+    np.testing.assert_array_equal(y, outside)
+
+
+def test_com_matmul_joins_the_residual_before_the_activation():
+    """``residual_first``: ``relu(acc + residual)``, a residual network's
+    join, in the kernel's epilogue."""
+    x, w, r = _operands()
+    y = com_matmul(x, w, residual=r, activation="relu", residual_first=True,
+                   interpret=True)
+    acc = com_matmul(x, w, interpret=True)
+    np.testing.assert_array_equal(y, jax.nn.relu(acc + r))
+    assert (y >= 0).all() and not np.array_equal(
+        y, com_matmul(x, w, residual=r, activation="relu", interpret=True))
+
+
+def test_com_matmul_padded_pads_the_residual_as_the_output():
+    """A residual of an unaligned ``(M, N)`` rides the same block padding
+    as the output, and its bytes are counted in the plan."""
+    x, w, r = _operands(100, 64, 200)
+    y = com_matmul_padded(x, w, residual=r, activation="relu",
+                          residual_first=True, block_m=64, block_n=128,
+                          interpret=True)
+    want = jax.nn.relu(jnp.dot(x, w, precision="highest") + r)
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+    plan = matmul_plan(100, 64, 200, 4, block_m=64, block_n=128,
+                       residual=True)
+    assert plan.joins and plan.residual_bytes == 4 * 100 * 200
+    no_res = matmul_plan(100, 64, 200, 4, block_m=64, block_n=128)
+    assert plan.pad_bytes - no_res.pad_bytes == 4 * 128 * 256
+
 @given(
     s=st.sampled_from([128, 256]),
     hd=st.sampled_from([64, 128]),

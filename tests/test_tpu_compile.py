@@ -7,7 +7,9 @@ whether the program fits the chip's 16 GB of HBM) at the real widths of
 ``vgg16-imagenet`` at batch 8: one test per distinct ``com_matmul_padded``
 shape class of the chain, and one for the whole chain; and the whole chain
 of each benchmark cell, whose blocks ``choose_blocks`` picks per layer
-within the kernel's default VMEM limit.
+within the kernel's default VMEM limit, a residual join's block included;
+and, in ``resnet50-imagenet``'s chain, that every join is inside its
+layer's kernel.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -92,7 +94,8 @@ def test_vgg16_chain_compiles_for_v5e(one_chip):
 
 @pytest.mark.parametrize("network, batch", [
     ("vgg16-imagenet", 1), ("vgg16-imagenet", 32), ("vgg11-cifar", 256),
-], ids=["vgg16-b1", "vgg16-b32", "vgg11-b256"])
+    ("resnet50-imagenet", 32), ("vgg11-cifar", 1),
+], ids=["vgg16-b1", "vgg16-b32", "vgg11-b256", "resnet50-b32", "vgg11-b1"])
 def test_cell_chain_compiles_for_v5e_unpadded(one_chip, network, batch):
     program = compile_program(resolve_network(network))
     layers = program.workload.layers
@@ -105,3 +108,30 @@ def test_cell_chain_compiles_for_v5e_unpadded(one_chip, network, batch):
     assert text.count(KERNEL_CALL) == len(layers)
     # the chosen blocks divide every matmul: no block padding, no slice
     assert "/matmul/pad/" not in text and "/matmul/unpad/" not in text
+
+
+def test_resnet50_joins_are_inside_their_kernels(one_chip):
+    """The lowered ResNet-50 chain adds no tensors outside the Pallas
+    kernels: each of its 16 joins is the epilogue of its layer's kernel,
+    which takes the shortcut as its third operand. The one add left is
+    the global average pool's reduction."""
+    import re
+
+    program = compile_program(resolve_network("resnet50-imagenet"))
+    layers = program.workload.layers
+    first = layers[0]
+    x = _spec((BATCH, first.h_in, first.w_in, first.c_in), one_chip)
+    ws = [_spec((l.k, l.k, l.c_in, l.c_out) if isinstance(l, ConvSpec)
+                else (l.c_in, l.c_out), one_chip) for l in layers]
+    text = jax.jit(jax_forward(program, interpret=False)).lower(
+        x, ws).as_text()
+    assert re.findall(r"= stablehlo\.add ", text) == []
+    assert text.count("applies stablehlo.add") == 1
+    calls = [line for line in text.splitlines()
+             if "@tpu_custom_call" in line]
+    assert len(calls) == len(layers) == 54
+    operands = [line.split("@tpu_custom_call(")[1].split(")")[0].count("%")
+                for line in calls]
+    joins = [l for l in layers if getattr(l, "residual_from", None)]
+    assert operands.count(3) == len(joins) == 16
+    assert operands.count(2) == len(layers) - 16
